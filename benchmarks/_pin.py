@@ -16,9 +16,11 @@ import sys
 from pathlib import Path
 from typing import Callable, List, Tuple
 
+from repro.launch.env import _merge_xla_flags
+
+PIN_XLA_FLAGS = ("--xla_cpu_multi_thread_eigen=false",
+                 "intra_op_parallelism_threads=1")
 PIN_ENV = {
-    "XLA_FLAGS": "--xla_cpu_multi_thread_eigen=false "
-                 "intra_op_parallelism_threads=1",
     "OMP_NUM_THREADS": "1",
     "OPENBLAS_NUM_THREADS": "1",
     "MKL_NUM_THREADS": "1",
@@ -39,6 +41,8 @@ def run_pinned(module: str, prefix: str, inproc_flag: str,
     if os.environ.get(inproc_flag):
         return inproc()
     env = dict(os.environ, **PIN_ENV, **{inproc_flag: "1"})
+    env["XLA_FLAGS"] = _merge_xla_flags(os.environ.get("XLA_FLAGS", ""),
+                                        PIN_XLA_FLAGS)
     proc = subprocess.run(
         [sys.executable, "-m", module],
         env=env, cwd=Path(__file__).resolve().parents[1],

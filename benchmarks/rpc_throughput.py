@@ -97,10 +97,13 @@ def _median_fetch(units, root, cache):
 
 
 def _spawn_worker(addr: str, data_root: Path, cache_dir: Path):
+    """The external worker host. The bench process's own nodes compute on
+    the default device, which may be a chip that serves one process at a
+    time, so this host computes on the CPU (JAX_PLATFORMS=cpu)."""
     env = dict(os.environ,
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src")
                + os.pathsep + os.environ.get("PYTHONPATH", ""),
-               REPRO_CACHE_DIR=str(cache_dir))
+               REPRO_CACHE_DIR=str(cache_dir), JAX_PLATFORMS="cpu")
     return subprocess.Popen(
         [sys.executable, "-m", "repro.dist.rpc", "work", "--addr", addr,
          "--pipeline", PIPELINE, "--data-root", str(data_root),
@@ -230,6 +233,7 @@ def _run_e2e(td: Path, ds, pipe, rows, report):
         bfs = cstats.get("bytes_from_storage", 0)
         e2e[phase] = {"seconds": round(dt, 3), "ok": ok,
                       "units": len(units_now), "cache_hit_commits": hits,
+                      "external_worker_role": "cpu-host",
                       "images_per_s": round(ok / dt, 3),
                       "gbps": round(in_bits / dt / 1e9, 3),
                       "bytes_from_cache": bfc,
@@ -239,7 +243,8 @@ def _run_e2e(td: Path, ds, pipe, rows, report):
                       "processed": runner.stats.processed}
         rows.append((f"rpc_e2e_images_per_s_{phase}", e2e[phase]["images_per_s"],
                      f"{ok}/{len(units_now)} ok in {dt:.2f}s over socket "
-                     f"transport, {hits} cache-hit commits"))
+                     f"transport, {hits} cache-hit commits; the external "
+                     f"worker is a CPU host (JAX_PLATFORMS=cpu)"))
         rows.append((f"rpc_e2e_effective_gbps_{phase}",
                      e2e[phase]["gbps"],
                      f"input bits consumed / wall-clock "

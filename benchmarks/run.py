@@ -1,41 +1,49 @@
-# One function per paper table/figure. Prints ``name,value,derived`` CSV.
+"""Run every benchmark module and print its ``name,value,derived`` CSV rows.
+
+Each module runs in a child process of its own (``python -m benchmarks.run
+--one NAME``), and this parent never imports JAX: a chip serves one process
+at a time, so a parent that had touched it would starve every child.
+"""
 from __future__ import annotations
 
+import argparse
+import importlib
+import subprocess
 import sys
-import traceback
 from pathlib import Path
+
+MODULES = ("table1_cost", "pipeline_throughput", "executor_throughput",
+           "cluster_throughput", "rpc_throughput", "locality_throughput",
+           "peer_fabric", "ingest_stream", "campaign_plan", "recovery",
+           "train_step_bench", "kernel_bench")
+
+
+def _run_one(name: str) -> None:
+    mod = importlib.import_module(f"benchmarks.{name}")
+    for n, v, d in mod.run():
+        print(f"{n},{v},{str(d).replace(',', ';')}", flush=True)
 
 
 def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--one", choices=MODULES,
+                    help="run one module in this process")
+    args = ap.parse_args()
+    if args.one:
+        _run_one(args.one)
+        return
     # artifacts live under benchmarks/out/ — gitignored, so a fresh checkout
     # doesn't have it; recreate rather than making every bench defensive
-    (Path(__file__).resolve().parent / "out").mkdir(parents=True,
-                                                    exist_ok=True)
-    from . import (campaign_plan, cluster_throughput, executor_throughput,
-                   ingest_stream, kernel_bench, locality_throughput,
-                   peer_fabric, pipeline_throughput, recovery, rpc_throughput,
-                   table1_cost, train_step_bench)
-    mods = [("table1_cost", table1_cost), ("pipeline_throughput", pipeline_throughput),
-            ("executor_throughput", executor_throughput),
-            ("cluster_throughput", cluster_throughput),
-            ("rpc_throughput", rpc_throughput),
-            ("locality_throughput", locality_throughput),
-            ("peer_fabric", peer_fabric),
-            ("ingest_stream", ingest_stream),
-            ("campaign_plan", campaign_plan),
-            ("recovery", recovery),
-            ("train_step", train_step_bench), ("kernels", kernel_bench)]
-    print("name,value,derived")
+    root = Path(__file__).resolve().parents[1]
+    (root / "benchmarks" / "out").mkdir(parents=True, exist_ok=True)
+    print("name,value,derived", flush=True)
     failed = 0
-    for name, mod in mods:
-        try:
-            for row in mod.run():
-                n, v, d = row
-                print(f"{n},{v},{str(d).replace(',', ';')}", flush=True)
-        except Exception as e:  # noqa: BLE001
+    for name in MODULES:
+        proc = subprocess.run([sys.executable, "-m", "benchmarks.run",
+                               "--one", name], cwd=root)
+        if proc.returncode != 0:
             failed += 1
-            print(f"{name}_FAILED,,{type(e).__name__}: {e}", flush=True)
-            traceback.print_exc(file=sys.stderr)
+            print(f"{name}_FAILED,,exit code {proc.returncode}", flush=True)
     if failed:
         raise SystemExit(1)
 

@@ -10,6 +10,9 @@ from pathlib import Path
 from repro.core import (LocalRunner, TieredStore, builtin_pipelines,
                         generate_jobs, paper_table1, resource_status,
                         synthesize_dataset)
+from repro.launch.env import enable_compile_cache
+
+enable_compile_cache()
 
 with tempfile.TemporaryDirectory() as td:
     td = Path(td)

@@ -14,6 +14,9 @@ from pathlib import Path
 from repro.core import (builtin_pipelines, query_available_work,
                         synthesize_dataset)
 from repro.dist import ClusterRunner
+from repro.launch.env import enable_compile_cache
+
+enable_compile_cache()
 
 with tempfile.TemporaryDirectory() as td:
     ds = synthesize_dataset(Path(td), "MASIVar-cluster", n_subjects=12,

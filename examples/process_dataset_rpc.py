@@ -20,6 +20,9 @@ from pathlib import Path
 from repro.core import (Provenance, builtin_pipelines, query_available_work,
                         synthesize_dataset)
 from repro.dist import ClusterRunner
+from repro.launch.env import enable_compile_cache
+
+enable_compile_cache()
 
 with tempfile.TemporaryDirectory() as td:
     td = Path(td)
@@ -28,6 +31,9 @@ with tempfile.TemporaryDirectory() as td:
     pipe = builtin_pipelines()["bias_correct"]
     units, _ = query_available_work(ds, pipe)
     print(f"work query: {len(units)} units")
+    print("roles: this process runs the coordinator and the nodes that "
+          "compute on the default device; the worker process is a CPU host "
+          "(JAX_PLATFORMS=cpu)")
 
     def run_once(tag):
         runner = ClusterRunner(pipe, ds.root, nodes=2, transport="rpc",
@@ -40,18 +46,26 @@ with tempfile.TemporaryDirectory() as td:
             time.sleep(0.01)
 
         # one worker host in its own process: joins via the CLI entrypoint,
-        # with its own input cache (REPRO_CACHE_DIR) like a real machine
+        # with its own input cache (REPRO_CACHE_DIR) like a real machine.
+        # This process's nodes hold the accelerator, and a chip serves one
+        # process at a time, so the second host computes on the CPU
         env = dict(os.environ,
                    PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""),
-                   REPRO_CACHE_DIR=str(td / "ext-cache"))
-        worker = subprocess.Popen(
-            [sys.executable, "-m", "repro.dist.rpc", "work",
-             "--addr", runner.server.addr_str, "--pipeline", pipe.name,
-             "--data-root", str(ds.root), "--node-id", "ext-host"],
-            env=env, text=True, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT)
-        t.join()
-        print(f"[{tag}] worker process said: {worker.communicate()[0].strip()}")
+                   REPRO_CACHE_DIR=str(td / "ext-cache"), JAX_PLATFORMS="cpu")
+        # its output goes to a file: a pipe read only after the run would
+        # block the worker once its logs filled the pipe's buffer
+        log = td / f"worker-{tag}.log"
+        with open(log, "w") as out:
+            worker = subprocess.Popen(
+                [sys.executable, "-m", "repro.dist.rpc", "work",
+                 "--addr", runner.server.addr_str, "--pipeline", pipe.name,
+                 "--data-root", str(ds.root), "--node-id", "ext-host"],
+                env=env, stdout=out, stderr=subprocess.STDOUT)
+            t.join()
+            worker.wait()
+        said = [ln for ln in log.read_text().splitlines()
+                if ln.startswith("ext-host")]
+        print(f"[{tag}] worker process said: {' / '.join(said)}")
         results = got["r"]
         counts = Counter(r.status for r in results)
         st = runner.stats

@@ -11,6 +11,9 @@ from pathlib import Path
 
 from repro.core import (LocalRunner, builtin_pipelines, generate_jobs,
                         query_available_work, synthesize_dataset)
+from repro.launch.env import enable_compile_cache
+
+enable_compile_cache()
 
 with tempfile.TemporaryDirectory() as td:
     # 1. a BIDS-organized dataset lands on the archive

@@ -6,7 +6,10 @@ KV/SSM caches, across three architecture families.
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.env import enable_compile_cache
 from repro.launch.serve import serve_batch
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 for arch in ("llama3.2-1b", "rwkv6-1.6b", "zamba2-1.2b"):

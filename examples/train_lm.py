@@ -10,6 +10,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.launch.env import enable_compile_cache
 from repro.launch.train import train
 
 
@@ -38,4 +39,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

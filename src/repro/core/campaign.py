@@ -274,10 +274,11 @@ def plan_campaign(cohorts: Sequence[Cohort], summaries=None, *,
     **Producer placement** (multi-stage DAGs): a parent's placement *is*
     the next stage's locality — its outputs write through into the host
     cache where it runs — so when a unit is assigned to a node, the input
-    digests its ``depends_on`` children declare are folded into that node's
-    planned warm set, and the children (assigned later: topological order)
-    score those predicted bytes through the same scorer as real summary
-    bytes. Children then shard to the node where their parents' outputs
+    digests each of its ``depends_on`` children declares are predicted warm
+    on that node for that child alone, and the children (assigned later:
+    topological order) score those predicted bytes through the same scorer
+    as real summary bytes. A unit never scores another unit's prediction:
+    nothing orders an unrelated producer's commit before it runs. Children then shard to the node where their parents' outputs
     will land. A ``depends_on`` cycle among admitted units raises
     ``ValueError``; edges to job_ids outside the campaign count as
     satisfied and score nothing.
@@ -320,32 +321,29 @@ def plan_campaign(cohorts: Sequence[Cohort], summaries=None, *,
             "pipeline_digest": cohort.pipeline_digest,
             "admitted": admitted, "excluded": len(cohort.excluded)})
 
-    # DAG edges among admitted units + predicted outputs per parent: a
-    # child's declared input digests are, by definition of depends_on, bytes
-    # its parents' commits will produce
+    # DAG edges among admitted units
     by_job = {u.job_id: k for k, u in enumerate(admitted_units)}
     children: Dict[int, List[int]] = {}
     indeg: Dict[int, int] = {}
-    produced: Dict[int, set] = {}
     for k, u in enumerate(admitted_units):
         ps = {by_job[str(d)] for d in getattr(u, "depends_on", None) or ()
               if str(d) in by_job}
         if not ps:
             continue
         indeg[k] = len(ps)
-        child_digests = set((u.input_digests or {}).values())
         for p in ps:
             children.setdefault(p, []).append(k)
-            if child_digests:
-                produced.setdefault(p, set()).update(child_digests)
 
     # pass 2 — assignment in topological order, stable by admission index
     # (a heap of ready units), so a dependency-free campaign walks exactly
     # the admission order the old single-pass planner did
     heap = [k for k in range(len(admitted_units)) if k not in indeg]
     heapq.heapify(heap)
-    planned: Dict[str, set] = {n: set() for n in nodes}
-    views = {n: _PlannedView(decoded[n], planned[n]) for n in nodes}
+    # per unit, the nodes its placed parents will commit its inputs to: a
+    # child's declared input digests are, by definition of depends_on, bytes
+    # its parents' commits produce. Only a unit's own parents predict its
+    # warmth — an unrelated unit's outputs carry no ordering guarantee
+    predicted: Dict[int, set] = {}
     assigned: Dict[str, List[WorkUnit]] = {n: [] for n in nodes}
     scores: Dict[str, int] = {}                      # job_id -> grant score
     loads: Dict[str, int] = {n: 0 for n in nodes}    # Σ bytes, tie-break
@@ -355,6 +353,9 @@ def plan_campaign(cohorts: Sequence[Cohort], summaries=None, *,
         k = heapq.heappop(heap)
         placed += 1
         u = admitted_units[k]
+        own = set((u.input_digests or {}).values())
+        views = {n: _PlannedView(decoded[n], own if n in predicted.get(k, ())
+                                 else set()) for n in nodes}
         target = best_node(u, nodes, views, loads) if nodes else None
         score = (unit_local_bytes(u, views[target])
                  if target is not None else 0)
@@ -364,7 +365,8 @@ def plan_campaign(cohorts: Sequence[Cohort], summaries=None, *,
             assigned[target].append(u)
             scores[u.job_id] = score
             loads[target] += u.total_input_bytes
-            planned[target].update(produced.get(k, ()))
+            for c in children.get(k, ()):
+                predicted.setdefault(c, set()).add(target)
         for c in children.get(k, ()):
             indeg[c] -= 1
             if indeg[c] == 0:

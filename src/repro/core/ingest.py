@@ -36,6 +36,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from ..kernels.checksum import QAStats
 from . import stream as stream_mod
 from .integrity import atomic_write_bytes
 from .manifest import DatasetManifest
@@ -60,6 +61,10 @@ class IngestRecord:
     dest: str = ""
     checksum: str = ""           # fused-QA device checksum (device_qa mode)
     sha256: str = ""             # content digest of the committed .npy bytes
+    # streamed device-QA mode: this scan's QAStats fields and its
+    # StreamReport (which fold ran, interpreted or not, per-stage times)
+    qa: Optional[dict] = None
+    stream: Optional[dict] = None
 
 
 def write_raw_dump(path: Path, vol: np.ndarray, *, subject: str, session: str,
@@ -146,7 +151,7 @@ def _fast_qa_fused(vol: np.ndarray, rule: IngestRule) -> Tuple[str, str]:
 
 
 def _fast_qa_streamed(vol: np.ndarray, rule: IngestRule
-                      ) -> Tuple[str, str, str, bytes,
+                      ) -> Tuple[str, QAStats, str, bytes,
                                  stream_mod.StreamReport]:
     """The streaming twin of :func:`_fast_qa_fused`: serialize the float32
     volume once, then chunk those bytes through the incremental sha256 and
@@ -154,20 +159,19 @@ def _fast_qa_streamed(vol: np.ndarray, rule: IngestRule
     content digest of the exact bytes about to be committed and the QA
     verdict land together — no load-then-verify second pass, and on an
     accelerator each chunk's fold dispatch overlaps the next chunk's
-    hashing. Returns ``(reason, checksum_hex, sha256_hex, npy_bytes,
-    report)``; the checksum is bit-identical to the one-shot kernel's
-    (same blocks, same fold order) and ``npy_bytes`` is what
-    :func:`ingest_directory` commits, so digest == sha256 of the file."""
+    hashing. Returns ``(reason, qa_stats, sha256_hex, npy_bytes,
+    report)``; the stats are bit-identical to the one-shot kernel's (same
+    blocks, same fold order) and ``npy_bytes`` is what
+    :func:`ingest_directory` commits, so digest == sha256 of the file. A
+    fold that fails raises: a scan is never accepted without its verdict."""
     vol = np.ascontiguousarray(vol, dtype=np.float32)
     buf = io.BytesIO()
     np.save(buf, vol)
     data = buf.getvalue()
     digest, st, rep = stream_mod.stream_verify_bytes(data)
-    if st is None:       # cannot happen for a C-order float32 .npy; be safe
-        reason, checksum = _fast_qa_fused(vol, rule)
-    else:
-        reason, checksum = _qa_verdict(st, vol, rule), f"{st.checksum:016x}"
-    return reason, checksum, digest, data, rep
+    if st is None:       # a C-order float32 .npy always folds
+        raise RuntimeError("streamed QA fold returned no verdict")
+    return _qa_verdict(st, vol, rule), st, digest, data, rep
 
 
 def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
@@ -215,8 +219,11 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
             continue
         payload: Optional[bytes] = None
         digest = ""
+        scan_qa = scan_stream = None
         if device_qa and streaming:
-            qa, checksum, digest, payload, rep = _fast_qa_streamed(vol, rule)
+            qa, st, digest, payload, rep = _fast_qa_streamed(vol, rule)
+            checksum = f"{st.checksum:016x}"
+            scan_qa, scan_stream = dataclasses.asdict(st), rep.to_dict()
             if stream_rep is None:
                 stream_rep = rep
             else:
@@ -227,7 +234,8 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
             qa, checksum = _fast_qa(vol, rule), ""
         if qa:
             records.append(IngestRecord(raw.name, "failed_qa", qa,
-                                        checksum=checksum))
+                                        checksum=checksum, qa=scan_qa,
+                                        stream=scan_stream))
             continue
         # BIDS placement + JSON sidecar (dcm2niix behaviour)
         sub, ses = meta["subject"], meta["session"]
@@ -246,7 +254,8 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
                            json.dumps(meta, indent=1).encode(), fsync=False)
         records.append(IngestRecord(raw.name, "ok",
                                     dest=str(base / f"{stem}.npy"),
-                                    checksum=checksum, sha256=digest))
+                                    checksum=checksum, sha256=digest,
+                                    qa=scan_qa, stream=scan_stream))
     manifest = DatasetManifest.scan(bids_root / dataset, name=dataset)
     report = {
         "dataset": dataset,

@@ -39,8 +39,11 @@ class PipelineSpec:
 
 
 class Pipeline:
+    """A stage: ``fn`` maps input arrays to output arrays, which may stay
+    on the device; :meth:`run` is the host boundary that returns numpy."""
+
     def __init__(self, spec: PipelineSpec,
-                 fn: Callable[[Dict[str, np.ndarray]], Dict[str, np.ndarray]]):
+                 fn: Callable[[Dict[str, np.ndarray]], Dict[str, object]]):
         self.spec = spec
         self.fn = fn
 
@@ -52,12 +55,22 @@ class Pipeline:
         return self.spec.digest()
 
     def run(self, inputs: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-        return self.fn(inputs)
+        return {k: np.asarray(v) for k, v in self.fn(inputs).items()}
 
 
 # ---------------------------------------------------------------------------
 # bias-field correction (N4-style)
 # ---------------------------------------------------------------------------
+# The geometric and least-squares contractions below are a handful of terms
+# wide, so they run at HIGHEST precision: at the TPU's default one-pass
+# bfloat16 a [-1, 1] grid coordinate loses ~2^-9 of its value, a quarter of
+# a voxel across a 256-voxel axis.
+HIGHEST = jax.lax.Precision.HIGHEST
+# Relative singular-value cut-off of the polynomial fit. lstsq's default,
+# eps * max(M, N), exceeds the basis' smallest singular-value ratio (~0.22)
+# beyond ~1.9M voxels, where it zeroed the whole fit at acquisition size.
+LSTSQ_RCOND = 1e-5
+
 
 def _poly_basis(shape, order):
     grids = [jnp.linspace(-1, 1, s) for s in shape]
@@ -74,8 +87,8 @@ def _poly_basis(shape, order):
 def _fit_bias(logv, basis):
     A = basis.reshape(-1, basis.shape[-1])
     b = logv.reshape(-1)
-    coef, *_ = jnp.linalg.lstsq(A, b)
-    return (A @ coef).reshape(logv.shape)
+    coef, *_ = jnp.linalg.lstsq(A, b, rcond=LSTSQ_RCOND)
+    return jnp.matmul(A, coef, precision=HIGHEST).reshape(logv.shape)
 
 
 def _bias_correct_fn(inputs):
@@ -84,8 +97,7 @@ def _bias_correct_fn(inputs):
     basis = _poly_basis(vol.shape, order=2)
     field = _fit_bias(logv - jnp.mean(logv), basis)
     corrected = jnp.exp(logv - field)
-    return {"T1w_biascorr": np.asarray(corrected, np.float32),
-            "bias_field": np.asarray(jnp.exp(field), np.float32)}
+    return {"T1w_biascorr": corrected, "bias_field": jnp.exp(field)}
 
 
 # ---------------------------------------------------------------------------
@@ -97,7 +109,8 @@ def _affine_grid(shape, theta):
     grids = [jnp.linspace(-1, 1, s) for s in shape]
     gx, gy, gz = jnp.meshgrid(*grids, indexing="ij")
     coords = jnp.stack([gx, gy, gz, jnp.ones_like(gx)], -1)     # (X,Y,Z,4)
-    warped = coords @ theta.T                                   # (X,Y,Z,3)
+    warped = jnp.einsum("xyzk,jk->xyzj", coords, theta,
+                        precision=HIGHEST)                      # (X,Y,Z,3)
     scale = (jnp.array(shape, jnp.float32) - 1) / 2
     return (warped + 1) * scale
 
@@ -137,9 +150,7 @@ def _register_fn(inputs, steps=60, lr=5e-3):
         return theta - lr * grad, val
     theta, losses = jax.lax.scan(body, theta, jnp.arange(steps))
     warped = _trilinear(moving, _affine_grid(shape, theta))
-    return {"T1w_reg": np.asarray(warped, np.float32),
-            "affine": np.asarray(theta, np.float32),
-            "reg_loss": np.asarray(losses, np.float32)}
+    return {"T1w_reg": warped, "affine": theta, "reg_loss": losses}
 
 
 # ---------------------------------------------------------------------------
@@ -171,8 +182,8 @@ def _segment_fn(inputs, n_classes=4, patch=4, seed=0):
                         params["lm_head"].astype(x.dtype))[0, :, :n_classes]
     seg = jnp.argmax(logits, -1).reshape(px, py, pz)
     seg_full = jnp.repeat(jnp.repeat(jnp.repeat(seg, patch, 0), patch, 1), patch, 2)
-    return {"segmentation": np.asarray(seg_full, np.int32),
-            "class_logits": np.asarray(logits, np.float32)}
+    return {"segmentation": seg_full.astype(jnp.int32),
+            "class_logits": logits.astype(jnp.float32)}
 
 
 # ---------------------------------------------------------------------------
@@ -181,11 +192,14 @@ def _segment_fn(inputs, n_classes=4, patch=4, seed=0):
 
 def builtin_pipelines() -> Dict[str, Pipeline]:
     return {
+        # 1.1: the fit's rcond and HIGHEST-precision contractions changed
+        # what bias_correct and affine_register compute, so the new digest
+        # re-plans every derivative an older version committed
         "bias_correct": Pipeline(
-            PipelineSpec("bias_correct", "1.0", ("T1w",), {"order": 2}),
+            PipelineSpec("bias_correct", "1.1", ("T1w",), {"order": 2}),
             _bias_correct_fn),
         "affine_register": Pipeline(
-            PipelineSpec("affine_register", "1.0", ("T1w",),
+            PipelineSpec("affine_register", "1.1", ("T1w",),
                          {"steps": 60, "lr": 5e-3}),
             _register_fn),
         "segment_unest": Pipeline(

@@ -28,6 +28,8 @@ the three verification stages all overlap the transfer itself:
 * **Honest fallbacks** — non-npy bytes, unsupported dtypes, Fortran-order
   payloads, or a truncated stream degrade to hash-only (``qa=None``); the
   sha256 is always computed and always identical to the resident path's.
+  A QA kernel that fails to compile or run is not a fallback case: its
+  error propagates, so a device fault can never pass as hash-only.
 
 Per-stage wall times land in a :class:`StreamReport` — ``overlap_s`` is the
 time the pipeline saved versus running the stages back-to-back — which the
@@ -50,6 +52,8 @@ from pathlib import Path
 from typing import Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
+
+from ..kernels.checksum import QAChecksumAccumulator, StreamSizeError
 
 STREAM_ENV = "REPRO_STREAM_INGEST"
 CHUNK_MB_ENV = "REPRO_STREAM_CHUNK_MB"
@@ -82,7 +86,10 @@ class StreamReport:
     chunk staging + QA fold dispatch (plus the final verdict sync);
     ``wall_s`` is end-to-end. Because the stages run overlapped,
     ``overlap_s = read_s + hash_s + device_s - wall_s`` is the time the
-    pipeline saved versus running them sequentially (clamped at 0)."""
+    pipeline saved versus running them sequentially (clamped at 0).
+    ``qa_backend`` is the fold that ran (``device`` or ``host``, comma-joined
+    after a merge of both) and ``qa_interpret`` whether a device fold ran
+    the Pallas interpreter (``None`` when no device fold ran)."""
     nbytes: int = 0
     chunks: int = 0
     chunk_bytes: int = 0
@@ -92,6 +99,8 @@ class StreamReport:
     wall_s: float = 0.0
     device_qa: bool = False
     files: int = 1
+    qa_backend: str = ""
+    qa_interpret: Optional[bool] = None
 
     @property
     def overlap_s(self) -> float:
@@ -106,7 +115,9 @@ class StreamReport:
                 "device_s": round(self.device_s, 6),
                 "wall_s": round(self.wall_s, 6),
                 "overlap_s": round(self.overlap_s, 6),
-                "device_qa": self.device_qa}
+                "device_qa": self.device_qa,
+                "qa_backend": self.qa_backend,
+                "qa_interpret": self.qa_interpret}
 
     @classmethod
     def from_dict(cls, d: dict) -> "StreamReport":
@@ -125,6 +136,10 @@ class StreamReport:
         self.wall_s += other.wall_s
         self.device_qa = self.device_qa or other.device_qa
         self.files += other.files
+        self.qa_backend = ",".join(sorted(
+            {b for r in (self, other) for b in r.qa_backend.split(",") if b}))
+        if other.qa_interpret is not None:
+            self.qa_interpret = bool(self.qa_interpret) or other.qa_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -239,8 +254,9 @@ def stream_chunks(chunks: Iterable[bytes], *, npy_qa: bool = False,
     off the first chunks and the payload folded through
     :class:`~repro.kernels.checksum.QAChecksumAccumulator`; anything the
     accumulator cannot fold bit-exactly (non-npy bytes, unsupported dtype,
-    Fortran order, truncation) degrades to ``qa=None`` — never an error and
-    never a wrong verdict. ``prefetch`` (a :class:`_Prefetcher`) lets
+    Fortran order, overrun or truncation) degrades to ``qa=None`` — never a
+    wrong verdict. A failure of the fold itself (a kernel that does not
+    compile or run) raises. ``prefetch`` (a :class:`_Prefetcher`) lets
     callers that already own the read thread contribute its link time."""
     t_wall = time.perf_counter()
     h = hashlib.sha256()
@@ -278,7 +294,6 @@ def stream_chunks(chunks: Iterable[bytes], *, npy_qa: bool = False,
                 head = b""
                 continue
             try:
-                from ..kernels.checksum import QAChecksumAccumulator
                 acc = QAChecksumAccumulator(n_vals, dtype,
                                             backend=qa_backend,
                                             interpret=interpret)
@@ -294,7 +309,7 @@ def stream_chunks(chunks: Iterable[bytes], *, npy_qa: bool = False,
             if take:
                 acc.update(take)
                 payload_fed += len(take)
-        except ValueError:             # overrun vs the declared shape
+        except StreamSizeError:        # overrun vs the declared shape
             qa_dead = True
             acc = None
     qa = None
@@ -303,7 +318,9 @@ def stream_chunks(chunks: Iterable[bytes], *, npy_qa: bool = False,
             qa = acc.finalize()
             rep.device_s += acc.device_seconds
             rep.device_qa = True
-        except ValueError:             # truncated vs the declared shape
+            rep.qa_backend = acc.backend
+            rep.qa_interpret = acc.interpret
+        except StreamSizeError:        # truncated vs the declared shape
             qa = None
     if prefetch is not None:
         rep.read_s += prefetch.read_s

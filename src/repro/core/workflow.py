@@ -642,6 +642,8 @@ def _main():
     if args.unit_from:
         print(unit.job_id)
         return
+    from ..launch.env import enable_compile_cache
+    enable_compile_cache()
     pipe = builtin_pipelines()[unit.pipeline]
     res = run_unit(unit, pipe, Path(args.data_root))
     print(f"{unit.job_id}: {res.status} ({res.seconds:.1f}s)")

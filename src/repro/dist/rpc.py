@@ -1009,7 +1009,17 @@ def _main():
     if not args.addr:
         ap.error(f"--addr or ${QUEUE_ADDR_ENV} is required")
     from .cluster import run_worker            # late: pulls in jax pipelines
+    from ..launch.env import enable_compile_cache
+    import jax
+    enable_compile_cache()
     node_id = args.node_id or f"{socket.gethostname()}-{os.getpid()}"
+    # one process per chip: a worker started beside the process that holds
+    # the accelerator runs with JAX_PLATFORMS=cpu, and says which it is
+    backend = jax.default_backend()
+    role = "cpu-host" if backend == "cpu" else "accelerator"
+    print(f"{node_id}: role {role}, computing on {backend} "
+          f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r})",
+          flush=True)
     if args.cache_dir:                       # explicit flags beat the env
         os.environ["REPRO_CACHE_DIR"] = args.cache_dir
     if args.cache_mb is not None:

@@ -15,8 +15,18 @@ replacing ~5 separate numpy passes (isfinite, std, mean, checksum, ...) in
 grids over the leading dim so a whole shape-bucket of volumes is verified in
 one launch. ``ref.py`` defines the identical functions in numpy; kernel and
 oracle agree bit-exactly — float sums use a fixed power-of-two halving tree
-on both sides (elementwise IEEE adds, no reassociation), integer checksums
-wrap mod 2^32.
+on both sides (elementwise IEEE adds, no reassociation, subnormals flushed
+to zero), integer checksums wrap mod 2^32.
+
+TPU layout. Operands are lane-dense ``(rows, 128)`` tiles. One QA block of
+:data:`~.ref.BLK` = 1024 values is exactly one ``(8, 128)`` float32 vreg, so
+the block's halving tree is a butterfly of sublane rolls (4, 2, 1) then lane
+rolls (64 … 1). Each roll is by half the current span, which makes the
+butterfly independent of the roll direction, and it leaves the block sum in
+every element. Every carry — the checksum, count, min and max partials and
+the running sum — is a whole ``(8, 128)`` tile, so the kernels never store a
+scalar to VMEM. The partial tiles are reduced to scalars by XLA after the
+call: integer sums wrap and min/max are exact, so that order is free.
 """
 from __future__ import annotations
 
@@ -29,8 +39,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ref import M_POS, qa_block_size, tree_sum_f32
+from .ref import BLK, EXP_MASK, M_POS, ftz_f32, qa_block_size, tree_sum_f32
+
+LANES = 128
+SUBLANES = BLK // LANES       # 8: one QA block == one (8, 128) f32 tile
+STEP_BLOCKS = 64              # QA blocks per grid step (256 KiB of f32)
+CHECKSUM_STEP_ROWS = 512      # word rows per step of the plain checksum
+
+
+class StreamSizeError(ValueError):
+    """The bytes fed to a :class:`QAChecksumAccumulator` disagree with the
+    array size it was declared for (overrun or truncated transfer)."""
 
 
 def _auto_interpret(interpret):
@@ -40,167 +61,295 @@ def _auto_interpret(interpret):
     return interpret
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+# ---------------------------------------------------------------------------
+# in-kernel helpers (each operates on one (8, 128) tile)
+# ---------------------------------------------------------------------------
+
+def _tile_pos():
+    """Flat element index of each position of an (8, 128) tile."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+    return row * LANES + lane
+
+
+def _ftz(x):
+    """Flush float32 subnormals (and -0.0) to +0.0 — ``ref.ftz_f32``."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where((bits & EXP_MASK) == 0, jnp.float32(0.0), x)
+
+
+def _tree_sum_f32(v):
+    """``ref.tree_sum_f32`` over one 1024-value block held as an (8, 128)
+    tile, as a roll butterfly: after the roll by half of span ``s`` every
+    element holds the tree node ``T[i mod s/2]``, so the block sum ends up
+    in every element whichever way the hardware rotates."""
+    for shift in (4, 2, 1):
+        v = _ftz(v + pltpu.roll(v, shift, 0))
+    for shift in (64, 32, 16, 8, 4, 2, 1):
+        v = _ftz(v + pltpu.roll(v, shift, 1))
+    return v
+
+
+def _fold_words(w, base, pos0, s1, s2):
+    """Add one (8, 128) tile of words whose first word has global index
+    ``base`` into the (s1, s2) partial tiles. Zero words (padding) add
+    nothing, so they need no mask."""
+    pos = base % M_POS + pos0
+    pos = jnp.where(pos >= M_POS, pos - M_POS, pos)
+    return s1 + w, s2 + w * pos
+
+
+def _rows(ref, j):
+    return ref[pl.ds(pl.multiple_of(j * SUBLANES, SUBLANES), SUBLANES), :]
+
+
 # ---------------------------------------------------------------------------
 # plain checksum (kept: the transfer-only fast path)
 # ---------------------------------------------------------------------------
 
-def _checksum_kernel(x_ref, o_ref, *, blk: int, n: int):
+def _checksum_kernel(w_ref, o_ref, *, rows: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = x_ref[...]                                     # (blk,) int32 words
-    idx = i * blk + jax.lax.iota(jnp.int32, blk)
-    valid = idx < n
-    w = jnp.where(valid, w, 0)
-    pos = jnp.where(valid, idx % M_POS, 0)
-    s1 = jnp.sum(w)                                    # int32 wrap-around
-    s2 = jnp.sum(w * pos)
-    o_ref[0] = o_ref[0] + s1
-    o_ref[1] = o_ref[1] + s2
+    pos0 = _tile_pos()
+
+    def body(j, s):
+        return _fold_words(_rows(w_ref, j), (i * rows + j * SUBLANES) * LANES,
+                           pos0, *s)
+
+    s1, s2 = jax.lax.fori_loop(0, rows // SUBLANES, body,
+                               (o_ref[0], o_ref[1]))
+    o_ref[0] = s1
+    o_ref[1] = s2
 
 
 def _to_words(x) -> jnp.ndarray:
     """Little-endian int32 word view of an array's bytes (zero-padded)."""
+    x = x.reshape(-1)
     if x.dtype.itemsize == 4:
-        return jax.lax.bitcast_convert_type(x.reshape(-1), jnp.int32)
-    b = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint8).reshape(-1)
-    pad = (-b.size) % 4
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    if x.dtype.itemsize < 4:
+        per = 4 // x.dtype.itemsize
+        x = jnp.pad(x, (0, (-x.size) % per)).reshape(-1, per)
+    return jax.lax.bitcast_convert_type(x, jnp.int32).reshape(-1)
+
+
+def _pad_rows(words, rows: int):
+    """Zero-pad a (..., n) int32 word stream to (..., rows, 128)."""
+    pad = rows * LANES - words.shape[-1]
     if pad:
-        b = jnp.concatenate([b, jnp.zeros(pad, jnp.uint8)])
-    quads = b.reshape(-1, 4).astype(jnp.int32) & 0xFF
-    return (quads[:, 0] | (quads[:, 1] << 8) | (quads[:, 2] << 16)
-            | (quads[:, 3] << 24))
+        widths = [(0, 0)] * (words.ndim - 1) + [(0, pad)]
+        words = jnp.pad(words, widths)
+    return words.reshape(words.shape[:-1] + (rows, LANES))
 
 
-@functools.partial(jax.jit, static_argnames=("blk", "interpret"))
-def device_checksum(x, *, blk: int = 1024, interpret: bool = False):
-    """x: any array. Returns int32[2] = (s1, s2) over its uint32 word view."""
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _device_checksum(x, *, interpret: bool):
     words = _to_words(x).reshape(-1)
-    n = words.size
-    blk = min(blk, max(n, 1))
-    pad = (-n) % blk
-    if pad:
-        words = jnp.concatenate([words, jnp.zeros(pad, jnp.int32)])
-    return pl.pallas_call(
-        functools.partial(_checksum_kernel, blk=blk, n=n),
-        grid=(words.size // blk,),
-        in_specs=[pl.BlockSpec((blk,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((2,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
+    rows = max(_round_up(-(-words.size // LANES), SUBLANES), SUBLANES)
+    step = min(CHECKSUM_STEP_ROWS, rows)
+    rows = _round_up(rows, step)
+    out = pl.pallas_call(
+        functools.partial(_checksum_kernel, rows=step),
+        grid=(rows // step,),
+        in_specs=[pl.BlockSpec((step, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((2, SUBLANES, LANES), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((2, SUBLANES, LANES), jnp.int32),
         interpret=interpret,
-    )(words)
+    )(_pad_rows(words, rows))
+    return jnp.sum(out, axis=(1, 2))
+
+
+def device_checksum(x, *, interpret=None):
+    """x: any array. Returns int32[2] = (s1, s2) over its uint32 word view."""
+    return _device_checksum(jnp.asarray(x),
+                            interpret=_auto_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
 # fused QA + checksum
 # ---------------------------------------------------------------------------
+# One kernel serves the one-shot, batched and chunk-accumulating entry
+# points. It folds a grid of (volume g, step i) over a carry that enters as
+# an operand: the one-shot call passes the empty carry, a stream chunk
+# passes the previous chunk's result. ``off`` (SMEM) holds the global word
+# and value offsets of the first element and the end of the valid values,
+# so a chunk's arithmetic is operation for operation the one-shot kernel's
+# — bit-exact for any chunking. Float32 volumes pass their words only (the
+# values are the same bits); other dtypes also pass their values widened to
+# float32, one block per (8, 128) tile.
 
-def _tree_sum_f32(v):
-    """Fixed halving-tree f32 sum; mirrors ``ref.tree_sum_f32`` bit-exactly."""
-    n = v.shape[0]
-    while n > 1:
-        n //= 2
-        v = v[:n] + v[n:2 * n]
-    return v[0]
-
-
-def _qa_checksum_kernel(w_ref, v_ref, sums_ref, qa_ref, cnt_ref, *,
-                        blk_w: int, blk_v: int, nw: int, nv: int):
+def _qa_kernel(off_ref, *refs, k_blocks: int, blk_v: int, w_rows: int,
+               conv: bool):
+    if conv:
+        w_ref, v_ref, ci_ref, cf_ref, oi_ref, of_ref = refs
+    else:
+        w_ref, ci_ref, cf_ref, oi_ref, of_ref = refs
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        sums_ref[...] = jnp.zeros_like(sums_ref)
-        qa_ref[0, 0] = jnp.float32(jnp.inf)
-        qa_ref[0, 1] = jnp.float32(-jnp.inf)
-        qa_ref[0, 2] = jnp.float32(0.0)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        oi_ref[...] = ci_ref[...]
+        of_ref[...] = cf_ref[...]
 
-    # checksum over the word view
-    w = w_ref[0, :]
-    idx = i * blk_w + jax.lax.iota(jnp.int32, blk_w)
-    valid = idx < nw
-    w = jnp.where(valid, w, 0)
-    pos = jnp.where(valid, idx % M_POS, 0)
-    sums_ref[0, 0] = sums_ref[0, 0] + jnp.sum(w)
-    sums_ref[0, 1] = sums_ref[0, 1] + jnp.sum(w * pos)
+    w0, v0, v_end = off_ref[0], off_ref[1], off_ref[2]
+    pos0 = _tile_pos()
 
-    # QA over the float value view (finite values only)
-    v = v_ref[0, :].astype(jnp.float32)
-    vidx = i * blk_v + jax.lax.iota(jnp.int32, blk_v)
-    finite = jnp.isfinite(v) & (vidx < nv)
-    cnt_ref[0, 0] = cnt_ref[0, 0] + jnp.sum(finite.astype(jnp.int32))
-    qa_ref[0, 0] = jnp.minimum(qa_ref[0, 0],
-                               jnp.min(jnp.where(finite, v, jnp.inf)))
-    qa_ref[0, 1] = jnp.maximum(qa_ref[0, 1],
-                               jnp.max(jnp.where(finite, v, -jnp.inf)))
-    qa_ref[0, 2] = qa_ref[0, 2] + _tree_sum_f32(jnp.where(finite, v, 0.0))
+    def words(j, w, s):
+        return _fold_words(w, w0 + (i * w_rows + j * SUBLANES) * LANES,
+                           pos0, *s)
+
+    def values(k, bits, q):
+        cnt, vmin, vmax, vsum = q
+        vidx = v0 + (i * k_blocks + k) * blk_v + pos0
+        valid = vidx < v_end
+        if blk_v < BLK:                       # one short, zero-padded block
+            valid = valid & (pos0 < blk_v)
+        exp = bits & EXP_MASK
+        finite = valid & (exp != EXP_MASK)
+        v = jnp.where(finite & (exp != 0),
+                      jax.lax.bitcast_convert_type(bits, jnp.float32),
+                      jnp.float32(0.0))
+        return (cnt + finite.astype(jnp.int32),
+                jnp.minimum(vmin, jnp.where(finite, v, jnp.inf)),
+                jnp.maximum(vmax, jnp.where(finite, v, -jnp.inf)),
+                _ftz(vsum + _tree_sum_f32(v)))
+
+    s = (oi_ref[0], oi_ref[1])
+    q = (oi_ref[2], of_ref[0], of_ref[1], of_ref[2])
+    if conv:
+        s = jax.lax.fori_loop(
+            0, w_rows // SUBLANES, lambda j, s: words(j, _rows(w_ref, j), s),
+            s)
+        q = jax.lax.fori_loop(
+            0, k_blocks, lambda k, q: values(
+                k, jax.lax.bitcast_convert_type(_rows(v_ref, k), jnp.int32),
+                q), q)
+    else:
+        def body(k, sq):
+            w = _rows(w_ref, k)
+            return words(k, w, sq[0]), values(k, w, sq[1])
+        s, q = jax.lax.fori_loop(0, k_blocks, body, (s, q))
+    oi_ref[0], oi_ref[1] = s
+    oi_ref[2], of_ref[0], of_ref[1], of_ref[2] = q
 
 
-@functools.partial(jax.jit, static_argnames=("blk", "interpret"))
-def _qa_checksum_2d(vals, *, blk: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("k_blocks", "blk_v",
+                                             "interpret"))
+def _qa_call(off, words, vals, ci, cf, *, k_blocks: int, blk_v: int,
+             interpret: bool):
+    """Run the fused kernel. ``words``: (G, w_rows_total, 128) int32;
+    ``vals``: (G, v_rows_total, 128) float32, or None for float32 volumes;
+    ``off``: int32[3] = (first word index, first value index, value end);
+    ``ci``/``cf``: the (G, 3, 8, 128) int32/float32 carry. Returns the new
+    carry."""
+    G = words.shape[0]
+    conv = vals is not None
+    nsteps = (vals if conv else words).shape[1] // (SUBLANES * k_blocks)
+    w_rows = words.shape[1] // nsteps
+    tile = lambda rows: pl.BlockSpec((None, rows, LANES),
+                                     lambda g, i: (g, i, 0))
+    carry = pl.BlockSpec((None, 3, SUBLANES, LANES),
+                         lambda g, i: (g, 0, 0, 0))
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), tile(w_rows)]
+    operands = [off, words]
+    if conv:
+        in_specs.append(tile(SUBLANES * k_blocks))
+        operands.append(vals)
+    return pl.pallas_call(
+        functools.partial(_qa_kernel, k_blocks=k_blocks, blk_v=blk_v,
+                          w_rows=w_rows, conv=conv),
+        grid=(G, nsteps),
+        in_specs=in_specs + [carry, carry],
+        out_specs=(carry, carry),
+        out_shape=(jax.ShapeDtypeStruct(ci.shape, jnp.int32),
+                   jax.ShapeDtypeStruct(cf.shape, jnp.float32)),
+        interpret=interpret,
+    )(*operands, ci, cf)
+
+
+def _empty_carry(G: int):
+    tile = jnp.ones((G, 1, SUBLANES, LANES), jnp.float32)
+    return (jnp.zeros((G, 3, SUBLANES, LANES), jnp.int32),
+            jnp.concatenate([tile * jnp.inf, tile * -jnp.inf, tile * 0.0],
+                            axis=1))
+
+
+def _finish(ci, cf):
+    """Carry tiles -> (sums int32 (G,2), qa f32 (G,3), cnt int32 (G,1))."""
+    sums = jnp.sum(ci[:, :2], axis=(2, 3))            # int32 wrap-around
+    cnt = jnp.sum(ci[:, 2], axis=(1, 2))[:, None]
+    qa = jnp.stack([jnp.min(cf[:, 0], axis=(1, 2)),
+                    jnp.max(cf[:, 1], axis=(1, 2)),
+                    cf[:, 2, 0, 0]], axis=1)
+    return sums, qa, cnt
+
+
+def _launch_shape(nblocks: int, blk_v: int, itemsize: int):
+    """(k_blocks, padded block count, word rows) of a launch over
+    ``nblocks`` QA blocks. A launch of fewer than :data:`STEP_BLOCKS`
+    blocks is one grid step; a longer one steps 64 full blocks at a time,
+    whose words fill whole (8, 128) tiles for every itemsize."""
+    nblocks = max(nblocks, 1)
+    k_blocks = min(STEP_BLOCKS, nblocks)
+    nb_pad = _round_up(nblocks, k_blocks)
+    words = nb_pad * blk_v * itemsize // 4
+    if itemsize == 4 or nb_pad > k_blocks:
+        w_rows = max(-(-words // LANES), SUBLANES)
+    else:
+        w_rows = _round_up(max(-(-words // LANES), 1), SUBLANES)
+    return k_blocks, nb_pad, w_rows
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _qa_checksum_2d(vals, *, interpret: bool):
     """Core batched op. vals: (G, nv) in the original dtype. Returns
     (sums int32 (G,2), qa f32 (G,3), cnt int32 (G,1))."""
     G, nv = vals.shape
     itemsize = vals.dtype.itemsize
-    blk_v = qa_block_size(nv, itemsize, blk)
-    blk_w = blk_v * itemsize // 4
+    blk_v = qa_block_size(nv, itemsize)
+    k_blocks, nb_pad, w_rows = _launch_shape(-(-nv // blk_v), blk_v,
+                                             itemsize)
     # pad each ROW's byte extent to a word boundary before packing, so words
     # never straddle volume boundaries (matches the per-row oracle padding)
     row_pad = 0
     while (nv + row_pad) * itemsize % 4:
         row_pad += 1
-    wvals = vals
-    if row_pad:
-        wvals = jnp.concatenate(
-            [vals, jnp.zeros((G, row_pad), vals.dtype)], axis=1)
-    words = _to_words(wvals).reshape(G, -1)
-    nw = words.shape[1]
-    nsteps = max(-(-nw // blk_w), -(-nv // blk_v), 1)
-    wpad = nsteps * blk_w - nw
-    if wpad:
-        words = jnp.concatenate(
-            [words, jnp.zeros((G, wpad), jnp.int32)], axis=1)
-    vpad = nsteps * blk_v - nv
-    if vpad:
-        vals = jnp.concatenate(
-            [vals, jnp.zeros((G, vpad), vals.dtype)], axis=1)
-    return pl.pallas_call(
-        functools.partial(_qa_checksum_kernel, blk_w=blk_w, blk_v=blk_v,
-                          nw=nw, nv=nv),
-        grid=(G, nsteps),
-        in_specs=[pl.BlockSpec((1, blk_w), lambda g, i: (g, i)),
-                  pl.BlockSpec((1, blk_v), lambda g, i: (g, i))],
-        out_specs=(pl.BlockSpec((1, 2), lambda g, i: (g, 0)),
-                   pl.BlockSpec((1, 3), lambda g, i: (g, 0)),
-                   pl.BlockSpec((1, 1), lambda g, i: (g, 0))),
-        out_shape=(jax.ShapeDtypeStruct((G, 2), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 3), jnp.float32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32)),
-        interpret=interpret,
-    )(words, vals)
+    wvals = jnp.pad(vals, ((0, 0), (0, row_pad))) if row_pad else vals
+    words = _pad_rows(_to_words(wvals).reshape(G, -1), w_rows)
+    fvals = None
+    if vals.dtype != jnp.float32:
+        fvals = _pad_rows(vals.astype(jnp.float32), nb_pad * SUBLANES)
+    off = jnp.array([0, 0, nv], jnp.int32)
+    return _finish(*_qa_call(off, words, fvals, *_empty_carry(G),
+                             k_blocks=k_blocks, blk_v=blk_v,
+                             interpret=interpret))
 
 
-def qa_checksum_batched(x, *, blk: int = 1024, interpret=None):
+def qa_checksum_batched(x, *, interpret=None):
     """Fused QA+checksum over a shape-bucket: ``x`` is (N, ...) — N volumes
     verified in ONE ``pallas_call`` (grid over the leading dim). Returns
     (int32 (N,2) checksums, float32 (N,3) [min,max,sum], int32 (N,1) counts).
     """
     x = jnp.asarray(x)
-    return _qa_checksum_2d(x.reshape(x.shape[0], -1), blk=blk,
+    return _qa_checksum_2d(x.reshape(x.shape[0], -1),
                            interpret=_auto_interpret(interpret))
 
 
-def qa_checksum(x, *, blk: int = 1024, interpret=None):
+def qa_checksum(x, *, interpret=None):
     """Unbatched fused QA+checksum: one device pass over ``x`` emitting
     ``(s1, s2)``, ``(min, max, sum)`` over finite values, and finite_count.
     Returns (int32[2], float32[3], int32[1]); see :func:`qa_stats` for a
     friendly view."""
     x = jnp.asarray(x)
-    sums, qa, cnt = _qa_checksum_2d(x.reshape(1, -1), blk=blk,
+    sums, qa, cnt = _qa_checksum_2d(x.reshape(1, -1),
                                     interpret=_auto_interpret(interpret))
     return sums[0], qa[0], cnt[0]
 
@@ -208,84 +357,16 @@ def qa_checksum(x, *, blk: int = 1024, interpret=None):
 # ---------------------------------------------------------------------------
 # chunk-accumulating variant (streaming ingest, repro.core.stream)
 # ---------------------------------------------------------------------------
-# The one-shot kernel above wants the whole volume resident before it can
-# launch — which is exactly the host-side pass the streaming ingest path
-# exists to kill. This variant folds the SAME per-block reduction over
-# arbitrary byte chunks as they arrive off the wire: each launch initialises
-# its outputs from the previous launch's (s1, s2, min, max, sum,
-# finite_count) carry and advances global word/value offsets, so the
-# arithmetic executed across all launches is operation-for-operation the
-# one-shot kernel's sequence — bit-exact by construction, for any chunking
-# (the accumulator below re-buffers to block alignment so callers may feed
-# arbitrary chunk sizes, including one chunk bigger than the volume).
-
-
-def _qa_chunk_kernel(w_ref, v_ref, off_ref, cs_ref, cqa_ref, ccnt_ref,
-                     sums_ref, qa_ref, cnt_ref, *, blk_w: int, blk_v: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        sums_ref[...] = cs_ref[...]
-        qa_ref[...] = cqa_ref[...]
-        cnt_ref[...] = ccnt_ref[...]
-
-    # checksum over the word view at the chunk's global word offset
-    w = w_ref[...]
-    idx = off_ref[0] + i * blk_w + jax.lax.iota(jnp.int32, blk_w)
-    valid = idx < off_ref[2]
-    w = jnp.where(valid, w, 0)
-    pos = jnp.where(valid, idx % M_POS, 0)
-    sums_ref[0] = sums_ref[0] + jnp.sum(w)
-    sums_ref[1] = sums_ref[1] + jnp.sum(w * pos)
-
-    # QA over the value view at the chunk's global value offset
-    v = v_ref[...].astype(jnp.float32)
-    vidx = off_ref[1] + i * blk_v + jax.lax.iota(jnp.int32, blk_v)
-    finite = jnp.isfinite(v) & (vidx < off_ref[3])
-    cnt_ref[0] = cnt_ref[0] + jnp.sum(finite.astype(jnp.int32))
-    qa_ref[0] = jnp.minimum(qa_ref[0],
-                            jnp.min(jnp.where(finite, v, jnp.inf)))
-    qa_ref[1] = jnp.maximum(qa_ref[1],
-                            jnp.max(jnp.where(finite, v, -jnp.inf)))
-    qa_ref[2] = qa_ref[2] + _tree_sum_f32(jnp.where(finite, v, 0.0))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("blk_w", "blk_v", "nsteps", "interpret"))
-def _qa_chunk_call(words, vals, off, carry_sums, carry_qa, carry_cnt, *,
-                   blk_w: int, blk_v: int, nsteps: int, interpret: bool):
-    return pl.pallas_call(
-        functools.partial(_qa_chunk_kernel, blk_w=blk_w, blk_v=blk_v),
-        grid=(nsteps,),
-        in_specs=[pl.BlockSpec((blk_w,), lambda i: (i,)),
-                  pl.BlockSpec((blk_v,), lambda i: (i,)),
-                  pl.BlockSpec((4,), lambda i: (0,)),
-                  pl.BlockSpec((2,), lambda i: (0,)),
-                  pl.BlockSpec((3,), lambda i: (0,)),
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=(pl.BlockSpec((2,), lambda i: (0,)),
-                   pl.BlockSpec((3,), lambda i: (0,)),
-                   pl.BlockSpec((1,), lambda i: (0,))),
-        out_shape=(jax.ShapeDtypeStruct((2,), jnp.int32),
-                   jax.ShapeDtypeStruct((3,), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
-        interpret=interpret,
-    )(words, vals, off, carry_sums, carry_qa, carry_cnt)
-
-
-def qa_checksum_chunk(words, vals, off, carry, *, blk_w: int, blk_v: int,
-                      interpret=None):
-    """One chunk launch of the accumulating kernel: fold ``nsteps`` blocks of
-    (``words``, ``vals``) — already block-padded — into ``carry``
-    (``(sums int32[2], qa f32[3], cnt int32[1])``). ``off`` is
-    ``int32[4] = (word_offset, value_offset, total_words, total_values)``;
-    offsets are traced (not static) so a fixed chunk size compiles once.
-    Returns the new carry."""
-    nsteps = max(words.shape[0] // blk_w, 1)
-    return _qa_chunk_call(words, vals, off, *carry, blk_w=blk_w, blk_v=blk_v,
-                          nsteps=nsteps, interpret=_auto_interpret(interpret))
-
+# The one-shot call wants the whole volume resident before it can launch —
+# which is exactly the host-side pass the streaming ingest path exists to
+# kill. The accumulator below folds the SAME per-block reduction over
+# arbitrary byte chunks as they arrive off the wire: each launch starts
+# from the previous launch's carry tiles at the chunk's global word/value
+# offsets, so the arithmetic executed across all launches is
+# operation-for-operation the one-shot kernel's sequence (it re-buffers to
+# block alignment so callers may feed arbitrary chunk sizes, including one
+# chunk bigger than the volume). Launch sizes are rounded up to a power of
+# two of blocks, so a stream of fixed-size chunks compiles once.
 
 # dtypes both backends fold identically: little-endian native numerics that
 # jnp.asarray round-trips losslessly (f64/i64 would silently downcast under
@@ -305,17 +386,20 @@ class QAChecksumAccumulator:
     last byte is in — the :class:`QAStats` verdict is available the moment
     the transfer completes, with no second pass over the bytes.
 
-    ``backend="device"`` launches the Pallas chunk kernel per fold (each
+    ``backend="device"`` launches the Pallas kernel per fold (each
     :meth:`update` stages its chunk host→device and dispatches
     asynchronously; only :meth:`finalize` blocks). ``backend="host"`` runs a
     vectorized numpy fold with the identical block tree — bit-exact with the
     kernel — for hosts without an accelerator. The default picks ``device``
     on TPU and ``host`` elsewhere (interpret-mode Pallas is for tests, not
-    data-plane throughput).
+    data-plane throughput). ``backend`` and ``interpret`` hold what was
+    actually used, for the stream report. A kernel that fails to compile or
+    run raises out of :meth:`update`/:meth:`finalize`; only a byte count
+    that disagrees with the declared size raises :class:`StreamSizeError`.
     """
 
-    def __init__(self, n_vals: int, dtype, *, blk: int = 1024,
-                 interpret=None, backend: str = "auto"):
+    def __init__(self, n_vals: int, dtype, *, interpret=None,
+                 backend: str = "auto"):
         self.dtype = np.dtype(dtype)
         if self.dtype.name not in ACCUMULATOR_DTYPES:
             raise ValueError(
@@ -325,26 +409,24 @@ class QAChecksumAccumulator:
             raise ValueError(f"negative n_vals {n_vals}")
         self.n_vals = int(n_vals)
         self.itemsize = self.dtype.itemsize
-        self.blk_v = qa_block_size(self.n_vals, self.itemsize, blk)
+        self.blk_v = qa_block_size(self.n_vals, self.itemsize)
         self.blk_w = self.blk_v * self.itemsize // 4
         self.align_bytes = self.blk_v * self.itemsize
-        self.nw = (self.n_vals * self.itemsize + 3) // 4
         self.total_blocks = max(-(-self.n_vals // self.blk_v), 1)
         if backend == "auto":
             backend = "device" if jax.default_backend() == "tpu" else "host"
         if backend not in ("device", "host"):
             raise ValueError(f"unknown backend {backend!r}")
         self.backend = backend
-        self.interpret = interpret
+        self.interpret = (_auto_interpret(interpret) if backend == "device"
+                          else None)
         self.device_seconds = 0.0      # staging + fold dispatch + final sync
         self._buf = bytearray()
         self._blocks_done = 0
         self._bytes_seen = 0
         self._stats: Optional[QAStats] = None
         if backend == "device":
-            self._carry = (jnp.zeros(2, jnp.int32),
-                           jnp.asarray([jnp.inf, -jnp.inf, 0.0], jnp.float32),
-                           jnp.zeros(1, jnp.int32))
+            self._carry = _empty_carry(1)
         else:
             self._s1 = np.uint32(0)
             self._s2 = np.uint32(0)
@@ -355,54 +437,50 @@ class QAChecksumAccumulator:
 
     # -- per-launch plumbing -------------------------------------------------
 
-    def _chunk_arrays(self, chunk: bytes, nblocks: int
-                      ) -> Tuple[np.ndarray, np.ndarray]:
-        """Block-pad one aligned chunk into (words, vals) launch operands —
-        the same zero-pad + mask discipline as the one-shot kernel, applied
-        at the chunk's global offset instead of index 0."""
-        vals = np.frombuffer(chunk, dtype=self.dtype)
-        want_v = nblocks * self.blk_v
-        if vals.size < want_v:
-            vals = np.concatenate(
-                [vals, np.zeros(want_v - vals.size, self.dtype)])
-        wpad = (-len(chunk)) % 4
-        words = np.frombuffer(bytes(chunk) + b"\0" * wpad, "<u4")
-        want_w = nblocks * self.blk_w
-        if words.size < want_w:
-            words = np.concatenate(
-                [words, np.zeros(want_w - words.size, np.uint32)])
-        return words.view(np.int32), vals
-
-    def _fold_device(self, words: np.ndarray, vals: np.ndarray, w0: int,
-                     v0: int):
-        off = np.array([w0, v0, self.nw, self.n_vals], np.int32)
+    def _fold_device(self, chunk: bytes, nblocks: int, w0: int, v0: int):
+        """Stage one aligned chunk as lane-dense tiles, zero-padded to a
+        power-of-two block count, and fold it into the device carry."""
         t0 = time.perf_counter()
-        self._carry = qa_checksum_chunk(
-            jnp.asarray(words), jnp.asarray(vals), jnp.asarray(off),
-            self._carry, blk_w=self.blk_w, blk_v=self.blk_v,
+        bucket = 1 << (nblocks - 1).bit_length()
+        k_blocks, nb_pad, w_rows = _launch_shape(bucket, self.blk_v,
+                                                 self.itemsize)
+        words = np.zeros(w_rows * LANES, np.uint32)
+        raw = np.frombuffer(bytes(chunk) + b"\0" * ((-len(chunk)) % 4),
+                            "<u4")
+        words[:raw.size] = raw
+        vals = None
+        if self.dtype != np.float32:
+            vals = np.zeros(nb_pad * BLK, np.float32)
+            vals[:len(chunk) // self.itemsize] = np.frombuffer(
+                chunk, self.dtype)
+            vals = jnp.asarray(vals.reshape(1, -1, LANES))
+        v_end = min(self.n_vals, v0 + nblocks * self.blk_v)
+        off = np.array([w0, v0, v_end], np.int32)
+        self._carry = _qa_call(
+            jnp.asarray(off), jnp.asarray(words.view(np.int32)
+                                          .reshape(1, -1, LANES)),
+            vals, *self._carry, k_blocks=k_blocks, blk_v=self.blk_v,
             interpret=self.interpret)
         self.device_seconds += time.perf_counter() - t0
 
-    def _fold_host(self, words: np.ndarray, vals: np.ndarray, w0: int,
-                   v0: int):
-        """Vectorized numpy twin of the chunk kernel. Integer checksums are
+    def _fold_host(self, chunk: bytes, nblocks: int, w0: int, v0: int):
+        """Vectorized numpy twin of the kernel. Integer checksums are
         associative mod 2^32, so whole-chunk sums match the kernel's
-        per-block folds bit-for-bit; the float sum keeps the kernel's exact
+        per-tile folds bit-for-bit; the float sum keeps the kernel's exact
         shape — per-block halving tree, then one sequential scalar add per
-        block in order."""
+        block in order, subnormals flushed throughout."""
         t0 = time.perf_counter()
-        w = words.view(np.uint32)
-        idx = w0 + np.arange(w.size, dtype=np.int64)
-        valid_w = idx < self.nw
+        w = np.frombuffer(bytes(chunk) + b"\0" * ((-len(chunk)) % 4), "<u4")
         with np.errstate(over="ignore"):
-            w = np.where(valid_w, w, np.uint32(0))
-            pos = np.where(valid_w, (idx % M_POS).astype(np.uint32),
-                           np.uint32(0))
+            pos = ((w0 + np.arange(w.size, dtype=np.int64)) % M_POS
+                   ).astype(np.uint32)
             self._s1 = np.uint32(self._s1 + np.sum(w, dtype=np.uint32))
             self._s2 = np.uint32(self._s2 + np.sum(w * pos, dtype=np.uint32))
-        nblocks = vals.size // self.blk_v
-        v = vals.astype(np.float32).reshape(nblocks, self.blk_v)
-        vidx = (v0 + np.arange(vals.size)).reshape(nblocks, self.blk_v)
+        v = np.zeros(nblocks * self.blk_v, np.float32)
+        with np.errstate(over="ignore", invalid="ignore"):
+            v[:len(chunk) // self.itemsize] = np.frombuffer(chunk, self.dtype)
+        v = ftz_f32(v).reshape(nblocks, self.blk_v)
+        vidx = (v0 + np.arange(v.size)).reshape(nblocks, self.blk_v)
         finite = np.isfinite(v) & (vidx < self.n_vals)
         self._cnt += int(np.sum(finite))
         self._vmin = np.minimum(self._vmin,
@@ -411,17 +489,16 @@ class QAChecksumAccumulator:
                                 np.float32(np.max(np.where(finite, v,
                                                            -np.inf))))
         for t in tree_sum_f32(np.where(finite, v, np.float32(0.0))):
-            self._vsum = np.float32(self._vsum + t)
+            self._vsum = np.float32(ftz_f32(self._vsum + t))
         self.device_seconds += time.perf_counter() - t0
 
     def _process(self, chunk: bytes, nblocks: int):
-        words, vals = self._chunk_arrays(chunk, nblocks)
         w0 = self._blocks_done * self.blk_w
         v0 = self._blocks_done * self.blk_v
         if self.backend == "device":
-            self._fold_device(words, vals, w0, v0)
+            self._fold_device(chunk, nblocks, w0, v0)
         else:
-            self._fold_host(words, vals, w0, v0)
+            self._fold_host(chunk, nblocks, w0, v0)
         self._blocks_done += nblocks
 
     # -- public surface ------------------------------------------------------
@@ -434,7 +511,7 @@ class QAChecksumAccumulator:
             raise RuntimeError("accumulator already finalized")
         self._bytes_seen += len(data)
         if self._bytes_seen > self.n_vals * self.itemsize:
-            raise ValueError(
+            raise StreamSizeError(
                 f"stream overrun: fed {self._bytes_seen} bytes for a "
                 f"{self.n_vals * self.itemsize}-byte array")
         self._buf += data
@@ -447,13 +524,13 @@ class QAChecksumAccumulator:
     def finalize(self) -> QAStats:
         """Fold the carried tail (zero-padded + masked exactly like the
         one-shot kernel's final block) and return the whole-array
-        :class:`QAStats`. Raises ``ValueError`` if the byte count fed does
-        not match the declared array size — a truncated transfer must fail
-        verification, not silently pass QA on a prefix."""
+        :class:`QAStats`. Raises :class:`StreamSizeError` if the byte count
+        fed does not match the declared array size — a truncated transfer
+        must fail verification, not silently pass QA on a prefix."""
         if self._stats is not None:
             return self._stats
         if self._bytes_seen != self.n_vals * self.itemsize:
-            raise ValueError(
+            raise StreamSizeError(
                 f"stream truncated: fed {self._bytes_seen} of "
                 f"{self.n_vals * self.itemsize} bytes")
         remaining = self.total_blocks - self._blocks_done
@@ -462,12 +539,11 @@ class QAChecksumAccumulator:
             self._buf.clear()
         if self.backend == "device":
             t0 = time.perf_counter()
-            sums = np.asarray(self._carry[0]).view(np.uint32)
-            qa = np.asarray(self._carry[1])
-            cnt = int(np.asarray(self._carry[2])[0])
+            sums, qa, cnt = (np.asarray(a)[0] for a in _finish(*self._carry))
+            sums = sums.view(np.uint32)
             self.device_seconds += time.perf_counter() - t0
             self._stats = QAStats(int(sums[0]), int(sums[1]), float(qa[0]),
-                                  float(qa[1]), float(qa[2]), cnt)
+                                  float(qa[1]), float(qa[2]), int(cnt[0]))
         else:
             self._stats = QAStats(int(self._s1), int(self._s2),
                                   float(self._vmin), float(self._vmax),
@@ -490,10 +566,9 @@ class QAStats:
         return ((self.s2 & 0xFFFFFFFF) << 32) | (self.s1 & 0xFFFFFFFF)
 
 
-def qa_stats(x, *, blk: int = 1024, interpret=None) -> QAStats:
+def qa_stats(x, *, interpret=None) -> QAStats:
     """Run :func:`qa_checksum` and pull the scalars to the host."""
-    import numpy as np
-    sums, qa, cnt = qa_checksum(x, blk=blk, interpret=interpret)
+    sums, qa, cnt = qa_checksum(x, interpret=interpret)
     sums = np.asarray(sums).view(np.uint32)
     qa = np.asarray(qa)
     return QAStats(int(sums[0]), int(sums[1]), float(qa[0]), float(qa[1]),

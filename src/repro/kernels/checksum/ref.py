@@ -6,6 +6,12 @@ SAME fixed reduction tree — a power-of-two halving tree inside each block
 (elementwise IEEE f32 adds, no library reassociation), then a sequential
 scalar add across blocks. Padding, masking, and block sizes are shared via
 :func:`qa_block_size`; keep any change mirrored in ``checksum.py``.
+
+Subnormal float32 values are flushed to +0.0 before min/max/sum, and every
+partial sum is flushed the same way. The TPU's vector unit flushes
+subnormals in hardware; doing it explicitly (by exponent bits, not by a
+float compare) makes this oracle, the host fold and the kernels agree on
+every backend, whatever its denormal mode.
 """
 from __future__ import annotations
 
@@ -14,6 +20,8 @@ from typing import Tuple
 import numpy as np
 
 M_POS = 65521
+BLK = 1024                 # values per QA block: one (8, 128) f32 vreg
+EXP_MASK = 0x7F800000      # float32 exponent bits
 
 
 def device_checksum_ref(x: np.ndarray) -> np.ndarray:
@@ -35,10 +43,11 @@ def device_checksum_ref(x: np.ndarray) -> np.ndarray:
 # fused QA + checksum oracle
 # ---------------------------------------------------------------------------
 
-def qa_block_size(n_vals: int, itemsize: int, blk: int = 1024) -> int:
+def qa_block_size(n_vals: int, itemsize: int) -> int:
     """Value-block size shared by kernel and oracle: a power of two whose
-    byte extent is word-aligned, shrunk toward small inputs."""
-    blk = 1 << (int(blk).bit_length() - 1)           # floor to power of two
+    byte extent is word-aligned, shrunk toward small inputs (never above
+    :data:`BLK`)."""
+    blk = BLK
     min_blk = max(8, 4 // itemsize)                  # word alignment floor
     while blk // 2 >= max(n_vals, min_blk) and (blk // 2) * itemsize % 4 == 0:
         blk //= 2
@@ -47,14 +56,22 @@ def qa_block_size(n_vals: int, itemsize: int, blk: int = 1024) -> int:
     return max(blk, min_blk)
 
 
+def ftz_f32(v):
+    """Flush float32 subnormals (and -0.0) to +0.0, by exponent bits."""
+    v = np.asarray(v, np.float32)
+    bits = v.view(np.uint32)
+    return np.where(bits & np.uint32(EXP_MASK), v, np.float32(0.0))
+
+
 def tree_sum_f32(v: np.ndarray) -> np.float32:
-    """Fixed power-of-two halving-tree sum (elementwise IEEE f32 adds).
-    The kernel runs the identical tree in jnp — bit-exact by construction."""
-    v = v.astype(np.float32, copy=True)
+    """Fixed power-of-two halving-tree sum (elementwise IEEE f32 adds, each
+    partial sum flushed to zero when subnormal). The kernel runs the
+    identical tree — bit-exact by construction."""
+    v = ftz_f32(v)
     n = v.shape[-1]
     while n > 1:
         n //= 2
-        v = v[..., :n] + v[..., n:2 * n]
+        v = ftz_f32(v[..., :n] + v[..., n:2 * n])
     return v[..., 0]
 
 
@@ -76,7 +93,7 @@ def qa_checksum_batched_ref(x: np.ndarray
     vals = x.reshape(G, -1)
     nv = vals.shape[1]
     blk_v = qa_block_size(nv, x.dtype.itemsize)
-    blk_w = blk_v * x.dtype.itemsize // 4
+    nsteps = max(-(-nv // blk_v), 1)
 
     sums = np.zeros((G, 2), np.uint32)
     qa = np.zeros((G, 3), np.float32)
@@ -84,37 +101,25 @@ def qa_checksum_batched_ref(x: np.ndarray
     for g in range(G):
         row = vals[g]
         words = _pack_words_ref(row.tobytes())
-        nw = words.size
-        nsteps = max(-(-nw // blk_w), -(-nv // blk_v), 1)
-        wpad = np.zeros(nsteps * blk_w, np.uint32)
-        wpad[:nw] = words
-        v = row.astype(np.float32)
-        vpad = np.zeros(nsteps * blk_v, np.float32)
-        vpad[:nv] = v
-        s1 = np.uint32(0)
-        s2 = np.uint32(0)
-        vmin = np.float32(np.inf)
-        vmax = np.float32(-np.inf)
-        vsum = np.float32(0.0)
-        n_fin = np.int32(0)
+        pos = (np.arange(words.size, dtype=np.int64) % M_POS).astype(np.uint32)
         with np.errstate(over="ignore"):
-            for i in range(nsteps):
-                w = wpad[i * blk_w:(i + 1) * blk_w]
-                idx = np.arange(i * blk_w, (i + 1) * blk_w, dtype=np.int64)
-                pos = np.where(idx < nw, (idx % M_POS).astype(np.uint32),
-                               np.uint32(0))
-                s1 = np.uint32(s1 + np.sum(w, dtype=np.uint32))
-                s2 = np.uint32(s2 + np.sum(w * pos, dtype=np.uint32))
-                vb = vpad[i * blk_v:(i + 1) * blk_v]
-                vidx = np.arange(i * blk_v, (i + 1) * blk_v)
-                finite = np.isfinite(vb) & (vidx < nv)
-                n_fin = np.int32(n_fin + np.int32(np.sum(finite)))
-                vmin = np.minimum(vmin, np.min(np.where(finite, vb, np.inf)))
-                vmax = np.maximum(vmax, np.max(np.where(finite, vb, -np.inf)))
-                vsum = np.float32(vsum + tree_sum_f32(np.where(finite, vb, 0.0)))
+            s1 = np.sum(words, dtype=np.uint32)
+            s2 = np.sum(words * pos, dtype=np.uint32)
+        with np.errstate(over="ignore", invalid="ignore"):
+            v = np.zeros(nsteps * blk_v, np.float32)
+            v[:nv] = row.astype(np.float32)
+        v = ftz_f32(v)
+        finite = np.isfinite(v) & (np.arange(v.size) < nv)
+        vmin = np.min(np.where(finite, v, np.float32(np.inf)))
+        vmax = np.max(np.where(finite, v, np.float32(-np.inf)))
+        block_sums = tree_sum_f32(
+            np.where(finite, v, np.float32(0.0)).reshape(nsteps, blk_v))
+        vsum = np.float32(0.0)
+        for t in block_sums:                 # sequential, in block order
+            vsum = ftz_f32(vsum + t)
         sums[g] = (s1, s2)
         qa[g] = (vmin, vmax, vsum)
-        cnt[g] = n_fin
+        cnt[g] = np.sum(finite)
     return sums.view(np.int32), qa, cnt
 
 

@@ -22,12 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-
-def _set_mesh(mesh):
-    """``jax.set_mesh`` appeared after 0.4.x; a ``Mesh`` is already a context
-    manager there, so fall back to entering the mesh itself."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
 from ..analysis.flops import step_flops, step_hbm_bytes
 from ..analysis.hlo_parse import HloCosts
 from ..analysis.roofline import (HW, collective_bytes_from_hlo, model_flops,
@@ -59,7 +53,7 @@ def lower_cell(cfg, shape, mesh, *, extra_tag: str = "", step_override=None,
     kind = rules_kind(shape)
     rules = Rules(mesh, kind, policy, global_batch=shape.global_batch)
     t0 = time.time()
-    with _set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         if shape.kind == "train":
             params, pshard, opt, oshard = state_specs(cfg, rules)
             batch, bshard = batch_specs(cfg, shape, rules, "train")
@@ -100,8 +94,6 @@ def lower_cell(cfg, shape, mesh, *, extra_tag: str = "", step_override=None,
 
     mem = summarize_memory(compiled.memory_analysis())
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     n_chips = mesh.devices.size
     # loop-aware collective accounting (per-chip byte totals; see hlo_parse)

@@ -25,11 +25,17 @@ What the profile sets (and why):
 Everything is fail-soft and override-safe: variables the process already
 has keep their values, a missing tcmalloc just drops the preload, and
 ``REPRO_ENV_PROFILE=off`` disables the whole profile.
+
+:func:`enable_compile_cache` turns on JAX's persistent compilation cache,
+so a process that repeats a compile (each SLURM ``--run-one`` task is a new
+process) reads it back instead. Entry points call it; library code never
+does.
 """
 from __future__ import annotations
 
 import os
 import shlex
+from pathlib import Path
 from typing import Dict, Mapping, Optional
 
 ENV_PROFILE_ENV = "REPRO_ENV_PROFILE"     # "off"/"0"/"none" disables
@@ -127,6 +133,25 @@ def format_exports(role: str = "worker",
     prof = env_profile(role, base=base)
     return "\n".join(f"export {k}={shlex.quote(v)}"
                      for k, v in sorted(prof.items()))
+
+
+def enable_compile_cache() -> Path:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX
+    already reads it, so no other directory is set), else the fixed
+    ``<checkout>/.jax_cache`` — never a pid-, time- or tempdir-based path,
+    which no later process would find again. JAX's one-second floor
+    stays: it keeps the stage compiles (tens of seconds on a TPU) and skips
+    the many sub-second CPU compiles, whose cache hits each log a
+    multi-kilobyte XLA:CPU feature-mismatch line in JAX 0.9. Call from an
+    entry point, before the first compile."""
+    import jax
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return Path(env_dir)
+    path = Path(__file__).resolve().parents[3] / ".jax_cache"
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    return path
 
 
 def _main():

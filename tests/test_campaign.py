@@ -419,3 +419,36 @@ def test_campaign_invariant_grid(dataset, n_nodes, warm_frac, max_shard):
     check_campaign_invariant([cohort, twin], summaries,
                              status={"disk_free_gb": 8.0},
                              max_shard_units=max_shard)
+
+
+def test_child_scores_only_its_own_parents_predicted_outputs():
+    """Two DAG cohorts whose children read the same digest: cohort A's
+    parent is warm on n0, so its child is predicted warm there; cohort B's
+    parent has nothing to score and goes cold, so its child must stay cold
+    too — A's prediction says nothing about when B's child's inputs land."""
+    from campaign_invariant import check_campaign_invariant
+    from repro.core.query import WorkUnit
+
+    def unit(ds, subject, digests, deps=()):
+        return WorkUnit(dataset=ds, subject=subject, session="01",
+                        pipeline="p", pipeline_digest="pd",
+                        inputs={f"in{i}": f"{subject}-{i}.npy"
+                                for i in range(len(digests))},
+                        out_dir=f"/out/{ds}/{subject}",
+                        input_digests={f"in{i}": d
+                                       for i, d in enumerate(digests)},
+                        input_bytes={f"in{i}": 1
+                                     for i in range(len(digests))},
+                        depends_on=list(deps))
+
+    pa, pb = unit("dsA", "s00", ["d0"]), unit("dsB", "s00", [])
+    ca = unit("dsA", "s01", ["d1"], [pa.job_id])
+    cb = unit("dsB", "s01", ["d1"], [pb.job_id])
+    cohorts = [Cohort("dsA", "p", "pd", [pa, ca], []),
+               Cohort("dsB", "p", "pd", [pb, cb], [])]
+    summary = DigestSummary()
+    summary.add("d0")
+    plan = check_campaign_invariant(cohorts, {"n0": summary})
+    node_of = {j: s.node_id for s in plan.shards for j in s.unit_ids}
+    assert node_of == {pa.job_id: "n0", ca.job_id: "n0",
+                       pb.job_id: None, cb.job_id: None}

@@ -78,6 +78,25 @@ def test_digest_change_triggers_reprocessing(dataset, tmp_path):
     assert len(work) == 6
 
 
+@pytest.mark.parametrize("name", ["bias_correct", "affine_register"])
+def test_changed_stage_replans_derivatives_of_its_old_version(name, dataset,
+                                                              tmp_path):
+    """bias_correct's fit (lstsq rcond) and both stages' contractions
+    (HIGHEST precision) changed what they compute, so their version moved
+    past 1.0: a derivative committed under 1.0 must be planned again, not
+    skipped as already processed."""
+    import dataclasses
+    pipe = builtin_pipelines()[name]
+    old = type(pipe)(dataclasses.replace(pipe.spec, version="1.0"),
+                     lambda inputs: {"stale": np.zeros(1, np.float32)})
+    assert old.digest() != pipe.digest()
+    plan = generate_jobs(dataset, old, tmp_path / "jobs")
+    LocalRunner(old, dataset.root).run(plan.units)
+    assert query_available_work(dataset, old)[0] == []
+    work, _ = query_available_work(dataset, pipe)
+    assert len(work) == len(plan.units) == 6
+
+
 def test_generate_jobs_writes_manifest_and_every_referenced_path(dataset, tmp_path):
     """Regression: the SLURM template interpolated ``{out_dir}/manifest.json``
     (and a logs dir for ``#SBATCH --output``) that generate_jobs never
@@ -173,3 +192,25 @@ def test_pipeline_outputs_sensible(dataset):
     seg = pipes["segment_unest"].run({"T1w": t1})
     assert seg["segmentation"].shape == t1.shape
     assert set(np.unique(seg["segmentation"])) <= {0, 1, 2, 3}
+
+
+def test_bias_correct_fits_beyond_two_million_voxels():
+    """lstsq's default singular-value cut-off, eps * max(M, N), grows with
+    the voxel count and passed the polynomial basis' smallest singular-value
+    ratio (~0.22) at ~1.9M voxels: at acquisition size the fit returned an
+    all-zero field and the stage changed nothing. A 128^3 volume (2.1M
+    voxels) with a known smooth bias must come out flattened."""
+    n = 128
+    g = np.linspace(-1, 1, n, dtype=np.float32)
+    gx, gy, gz = np.meshgrid(g, g, g, indexing="ij")
+    bias = np.exp(0.3 * gx + 0.2 * gy * gy - 0.1 * gz)
+    rng = np.random.default_rng(0)
+    t1 = (100.0 * bias * rng.uniform(0.9, 1.1, bias.shape)).astype(np.float32)
+    out = builtin_pipelines()["bias_correct"].run({"T1w": t1})
+    assert out["T1w_biascorr"].dtype == out["bias_field"].dtype == np.float32
+    # the recovered field tracks the imposed one (up to a constant factor)
+    lf, lb = np.log(out["bias_field"]).ravel(), np.log(bias).ravel()
+    assert np.corrcoef(lf, lb)[0, 1] > 0.99
+    # what is left is the multiplicative noise, cv 0.2 / sqrt(12) = 0.058
+    cv = lambda a: a.std() / a.mean()
+    assert cv(t1) > 0.2 and cv(out["T1w_biascorr"]) < 0.07

@@ -136,3 +136,38 @@ def test_shard_script_evals_env_profile_before_python(tmp_path):
     # lands before the worker python starts
     assert "|| true" in s
     assert s.index("repro.launch.env") < s.index("repro.core.workflow")
+
+
+@pytest.mark.parametrize("setting", ["set", "unset", "empty"])
+def test_enable_compile_cache_lands_where_configured(tmp_path, setting):
+    """In a fresh process (jax reads the variable at import): with the
+    variable set the cache is written there; unset or empty, jax is pointed
+    at the checkout's fixed directory, never a temporary or per-process
+    path."""
+    import json
+    import os
+    import subprocess
+    import sys
+    repo = Path(__file__).resolve().parents[1]
+    # cache even this sub-second compile, so that something lands
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    from_env = setting == "set"
+    if setting != "unset":
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path) if from_env else ""
+    code = ("import json, jax, jax.numpy as jnp\n"
+            "from repro.launch.env import enable_compile_cache\n"
+            "p = enable_compile_cache()\n"
+            + ("jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0))"
+               ".block_until_ready()\n" if from_env else "")
+            + "print(json.dumps([str(p), jax.config.jax_compilation_cache_dir"
+              "]))\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=tmp_path,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    path, configured = json.loads(out.stdout.strip().splitlines()[-1])
+    want = tmp_path if from_env else repo / ".jax_cache"
+    assert Path(path) == Path(configured) == want
+    if from_env:
+        assert any(tmp_path.iterdir()), "nothing was cached"

@@ -232,9 +232,16 @@ def test_moe_dispatch_conservation(S, E, C):
             assert np.allclose(np.asarray(buf)[row], 0.0)
 
 
-@given(st.lists(st.floats(-1e4, 1e4, allow_nan=True, allow_infinity=True,
-                          width=32),
-                min_size=0, max_size=600),
+# NaN and +-inf come from sampled_from: hypothesis refuses allow_nan with
+# bounds. The finite draw includes float32 subnormals, which every fold
+# flushes to zero (repro.kernels.checksum.ref.ftz_f32).
+_QA_VALUES = st.one_of(
+    st.floats(-1e4, 1e4, width=32),
+    st.sampled_from([float("nan"), float("inf"), float("-inf"), -0.0,
+                     1.805228351860303e-39, -1e-40]))
+
+
+@given(st.lists(_QA_VALUES, min_size=0, max_size=600),
        st.integers(1, 3000),
        st.sampled_from(["float32", "float16", "int16", "uint8"]))
 @settings(max_examples=40, deadline=None)

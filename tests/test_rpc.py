@@ -476,15 +476,22 @@ def test_acceptance_64_units_chaos_over_socket_with_worker_process(tmp_path):
     env = dict(os.environ,
                PYTHONPATH=str(REPO_ROOT / "src") + os.pathsep
                + os.environ.get("PYTHONPATH", ""),
-               REPRO_CACHE_DIR=str(tmp_path / "ext-cache"))
-    worker = subprocess.Popen(
-        [sys.executable, "-m", "repro.dist.rpc", "work",
-         "--addr", runner.server.addr_str, "--pipeline", "bias_correct",
-         "--data-root", str(ds.root), "--node-id", "ext-0"],
-        env=env, cwd=REPO_ROOT, text=True,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-    t.join(timeout=300)
-    wout, _ = worker.communicate(timeout=60)
+               REPRO_CACHE_DIR=str(tmp_path / "ext-cache"),
+               # a compile cache of its own: one warmed by earlier runs
+               # would speed the worker up enough to change the schedule
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax-cache"))
+    # worker output goes to a file: a pipe drained only after the run
+    # would block the worker as soon as its logs filled the pipe buffer
+    wlog = tmp_path / "worker.log"
+    with open(wlog, "w") as out:
+        worker = subprocess.Popen(
+            [sys.executable, "-m", "repro.dist.rpc", "work",
+             "--addr", runner.server.addr_str, "--pipeline", "bias_correct",
+             "--data-root", str(ds.root), "--node-id", "ext-0"],
+            env=env, cwd=REPO_ROOT, stdout=out, stderr=subprocess.STDOUT)
+        t.join(timeout=300)
+        worker.wait(timeout=60)
+    wout = wlog.read_text()
     assert not t.is_alive(), "coordinator did not finish"
     results = got["r"]
     by_status = Counter(r.status for r in results)

@@ -289,3 +289,121 @@ def test_run_unit_stamps_stream_provenance(tmp_path):
     assert prov is not None and prov.stream is not None
     assert prov.stream["nbytes"] == (root / rel).stat().st_size
     assert prov.stream["overlap_s"] >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# subnormals, fold failures, and what the report says ran
+# ---------------------------------------------------------------------------
+
+def test_subnormal_is_flushed_by_every_fold():
+    """The hypothesis property's counterexample, pinned: a lone float32
+    subnormal. The host fold used to keep it (min = max = sum = 1.8e-39)
+    while the device folds flushed some of it; every path now flushes it to
+    zero before min/max/sum, and the oracle agrees."""
+    from repro.kernels.checksum import qa_checksum_ref
+    arr = np.asarray([1.805228351860303e-39], np.float32)
+    want = qa_stats(arr, interpret=True)
+    assert (want.vmin, want.vmax, want.vsum, want.finite_count) == \
+        (0.0, 0.0, 0.0, 1)
+    sums, qa, cnt = qa_checksum_ref(arr)
+    assert (int(sums.view(np.uint32)[0]), int(sums.view(np.uint32)[1]),
+            *map(float, qa), int(cnt[0])) == \
+        (want.s1, want.s2, want.vmin, want.vmax, want.vsum,
+         want.finite_count)
+    for backend in ("host", "device"):
+        acc = QAChecksumAccumulator(arr.size, arr.dtype, backend=backend,
+                                    interpret=True)
+        data = arr.tobytes()
+        for off in range(0, len(data), 1):          # chunk = 1 byte
+            acc.update(data[off:off + 1])
+        assert acc.finalize() == want, backend
+
+
+def test_subnormal_partial_sums_are_flushed():
+    """Two normal values whose sum is subnormal: the block tree flushes the
+    partial sum, on the kernel, the host fold and the oracle alike."""
+    from repro.kernels.checksum import qa_checksum_ref
+    arr = np.asarray([1.5e-38, -1.4e-38], np.float32)
+    want = qa_stats(arr, interpret=True)
+    assert want.vsum == 0.0
+    assert float(qa_checksum_ref(arr)[1][2]) == 0.0
+    acc = QAChecksumAccumulator(arr.size, arr.dtype, backend="host")
+    acc.update(arr.tobytes())
+    assert acc.finalize() == want
+
+
+def _fold_fails(monkeypatch):
+    def boom(self, chunk, nblocks):
+        raise RuntimeError("QA kernel failed")
+    monkeypatch.setattr(QAChecksumAccumulator, "_process", boom)
+
+
+def test_failed_fold_raises_instead_of_hash_only(tmp_path, monkeypatch):
+    vol = _volume((16, 16, 16), np.float32)
+    p = tmp_path / "v.npy"
+    np.save(p, vol)
+    _fold_fails(monkeypatch)
+    with pytest.raises(RuntimeError, match="QA kernel failed"):
+        stream_chunks(bytes_chunks(p.read_bytes(), 4096), npy_qa=True,
+                      chunk_bytes=4096)
+
+
+def test_failed_device_kernel_propagates(monkeypatch):
+    """A kernel error on the device backend (a compile refusal raises
+    ValueError) is not read as an overrun or truncation."""
+    from repro.kernels.checksum import checksum as ck
+
+    def refuse(*a, **k):
+        raise ValueError("Cannot store scalars to VMEM")
+    monkeypatch.setattr(ck, "_qa_call", refuse)
+    buf = io.BytesIO()
+    np.save(buf, _volume((16, 16, 16), np.float32))
+    with pytest.raises(ValueError, match="VMEM"):
+        stream_verify_bytes(buf.getvalue(), chunk_bytes=4096,
+                            qa_backend="device", interpret=True)
+
+
+def test_ingest_raises_when_the_fold_fails(tmp_path, monkeypatch):
+    from repro.core.ingest import ingest_directory, write_raw_dump
+    raw = tmp_path / "raw"
+    write_raw_dump(raw / "a.raw.npz", np.abs(_volume((16, 16, 16),
+                                                     np.float32)) + 1,
+                   subject="01", session="01", protocol="T1w")
+    _fold_fails(monkeypatch)
+    with pytest.raises(RuntimeError, match="QA kernel failed"):
+        ingest_directory(raw, tmp_path / "bids", "ds", device_qa=True)
+
+
+@pytest.mark.parametrize("backend,interpret", [("host", None),
+                                               ("device", True)])
+def test_stream_report_records_the_fold_that_ran(backend, interpret):
+    buf = io.BytesIO()
+    np.save(buf, _volume((16, 16, 16), np.float32))
+    _, qa, rep = stream_verify_bytes(buf.getvalue(), chunk_bytes=4096,
+                                     qa_backend=backend, interpret=True)
+    assert qa is not None
+    assert (rep.qa_backend, rep.qa_interpret) == (backend, interpret)
+    d = rep.to_dict()
+    assert (d["qa_backend"], d["qa_interpret"]) == (backend, interpret)
+    merged = StreamReport.from_dict(d)
+    merged.merge(StreamReport(qa_backend="host"))
+    assert merged.qa_backend == ",".join(sorted({backend, "host"}))
+
+
+def test_ingest_records_carry_per_scan_qa_and_stream(tmp_path):
+    from repro.core.ingest import ingest_directory, write_raw_dump
+    raw = tmp_path / "raw"
+    vols = {}
+    for sub in ("01", "02"):
+        vols[sub] = np.abs(_volume((16, 16, 16), np.float32)) + 1
+        write_raw_dump(raw / f"{sub}.raw.npz", vols[sub], subject=sub,
+                       session="01", protocol="T1w")
+    _, recs = ingest_directory(raw, tmp_path / "bids", "ds", device_qa=True)
+    for rec, sub in zip(recs, ("01", "02")):
+        assert rec.status == "ok"
+        assert rec.stream["device_qa"] is True
+        assert rec.stream["qa_backend"] == "host"      # auto, off TPU
+        st = qa_stats(vols[sub], interpret=True)
+        assert rec.qa["finite_count"] == st.finite_count
+        assert rec.qa["vsum"] == st.vsum
+        assert rec.checksum == f"{st.checksum:016x}"
