@@ -92,52 +92,29 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileMeter:
-    """Sums JAX's own compile events while active: tracing + lowering,
-    backend compile (a persistent-cache hit is timed here as its
-    retrieval), and cache hits/misses."""
+@contextlib.contextmanager
+def compile_timing(into: dict):
+    """Wall seconds of the block, and the program's compile counters over it
+    (``repro.core.trace``): tracing + lowering + backend compile (a
+    persistent-cache hit is timed as its retrieval), and cache hits and
+    misses."""
+    from repro.core import trace
+    before = trace.counters()
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        after = trace.counters()
 
-    def __init__(self):
-        import jax
-        self.active = False
-        self.reset()
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def reset(self):
-        self.trace_s = self.backend_s = 0.0
-        self.hits = self.misses = 0
-
-    def _duration(self, event, duration, **_):
-        if not self.active:
-            return
-        if event in ("/jax/core/compile/jaxpr_trace_duration",
-                     "/jax/core/compile/jaxpr_to_mlir_module_duration"):
-            self.trace_s += duration
-        elif event == "/jax/core/compile/backend_compile_duration":
-            self.backend_s += duration
-
-    def _event(self, event, **_):
-        if not self.active:
-            return
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    @contextlib.contextmanager
-    def measure(self, into: dict):
-        self.reset()
-        self.active = True
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.active = False
-            into.update(wall_s=time.perf_counter() - t0,
-                        compile_s=self.trace_s + self.backend_s,
-                        backend_compile_s=self.backend_s,
-                        cache_hits=self.hits, cache_misses=self.misses)
+        def grew(key):
+            return after.get(key, 0) - before.get(key, 0)
+        backend = grew("compile.backend.s")
+        into.update(wall_s=time.perf_counter() - t0,
+                    compile_s=grew("compile.trace.s") + grew("compile.lower.s")
+                    + backend,
+                    backend_compile_s=backend,
+                    cache_hits=grew("compile.cache_hits"),
+                    cache_misses=grew("compile.cache_misses"))
 
 
 class Smoke:
@@ -148,7 +125,6 @@ class Smoke:
         self.checks: list = []
         self.report: dict = {"checks": self.checks, "stages": {},
                              "ingest": {}, "kernels": {}, "reference": {}}
-        self.meter = CompileMeter()
 
     # -- bookkeeping ----------------------------------------------------------
 
@@ -210,7 +186,7 @@ class Smoke:
         write_raw_dump(raw / "sub-01_ses-01_dwi.raw.npz", dwi, subject="01",
                        session="01", protocol="dwi")
         timing: dict = {}
-        with self.meter.measure(timing):
+        with compile_timing(timing):
             manifest, records = ingest_directory(raw, work / "bids", "smoke",
                                                  device_qa=True)
         self.report["ingest"]["timing"] = timing
@@ -273,7 +249,7 @@ class Smoke:
         ]
         for name, run, ref in cases:
             timing: dict = {}
-            with self.meter.measure(timing):
+            with compile_timing(timing):
                 got = jax.block_until_ready(run())
             t0 = time.perf_counter()
             jax.block_until_ready(run())
@@ -302,7 +278,7 @@ class Smoke:
             self.check(f"{name}: one unit generated", len(plan.units) == 1,
                        f"{len(plan.units)} units")
             timing: dict = {}
-            with self.meter.measure(timing):
+            with compile_timing(timing):
                 results = LocalRunner(pipe, manifest.root).run(plan.units)
             rep["first_call"] = timing
             for res in results:
@@ -313,10 +289,10 @@ class Smoke:
                            res.error or "")
             # the second call, on the same inputs, for steady seconds; a
             # stage that is not one jitted program traces again here and
-            # reads the persistent cache, which the meter counts apart
+            # reads the persistent cache, which the counters count apart
             inputs = load_unit_inputs(plan.units[0], manifest.root)[0]
             steady: dict = {}
-            with self.meter.measure(steady):
+            with compile_timing(steady):
                 out = jax.block_until_ready(pipe.fn(inputs))
             rep["second_call"] = steady
             rep["steady_s"] = steady["wall_s"]

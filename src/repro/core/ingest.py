@@ -38,6 +38,7 @@ import numpy as np
 
 from ..kernels.checksum import QAStats
 from . import stream as stream_mod
+from . import trace
 from .integrity import atomic_write_bytes
 from .manifest import DatasetManifest
 
@@ -150,6 +151,16 @@ def _fast_qa_fused(vol: np.ndarray, rule: IngestRule) -> Tuple[str, str]:
     return _qa_verdict(st, vol, rule), f"{st.checksum:016x}"
 
 
+def _serialize(vol: np.ndarray) -> Tuple[np.ndarray, bytes]:
+    """The float32 volume and its ``.npy`` bytes, as the BIDS tree holds
+    them."""
+    with trace.span("ingest.serialize", bytes=vol.size * 4):
+        vol = np.ascontiguousarray(vol, dtype=np.float32)
+        buf = io.BytesIO()
+        np.save(buf, vol)
+        return vol, buf.getvalue()
+
+
 def _fast_qa_streamed(vol: np.ndarray, rule: IngestRule
                       ) -> Tuple[str, QAStats, str, bytes,
                                  stream_mod.StreamReport]:
@@ -164,11 +175,9 @@ def _fast_qa_streamed(vol: np.ndarray, rule: IngestRule
     blocks, same fold order) and ``npy_bytes`` is what
     :func:`ingest_directory` commits, so digest == sha256 of the file. A
     fold that fails raises: a scan is never accepted without its verdict."""
-    vol = np.ascontiguousarray(vol, dtype=np.float32)
-    buf = io.BytesIO()
-    np.save(buf, vol)
-    data = buf.getvalue()
-    digest, st, rep = stream_mod.stream_verify_bytes(data)
+    vol, data = _serialize(vol)
+    with trace.span("ingest.verify", bytes=len(data)):
+        digest, st, rep = stream_mod.stream_verify_bytes(data)
     if st is None:       # a C-order float32 .npy always folds
         raise RuntimeError("streamed QA fold returned no verdict")
     return _qa_verdict(st, vol, rule), st, digest, data, rep
@@ -198,80 +207,98 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
     raw_dir, bids_root = Path(raw_dir), Path(bids_root)
     records: List[IngestRecord] = []
     stream_rep: Optional[stream_mod.StreamReport] = None
-    for raw in sorted(raw_dir.glob("*.npz")):
-        vol, meta, err = _convert(raw)
-        if err:
-            records.append(IngestRecord(raw.name, "corrupted", err))
-            continue
-        proto = meta["protocol"]
-        if proto not in rule.allowed_protocols:
-            records.append(IngestRecord(raw.name, "filtered",
-                                        f"protocol {proto} not in allow-list"))
-            continue
-        res = float(meta["resolution_mm"])
-        if not (rule.min_resolution_mm <= res <= rule.max_resolution_mm):
-            records.append(IngestRecord(raw.name, "filtered",
-                                        f"resolution {res}mm out of bounds"))
-            continue
-        if min(vol.shape[:3]) < rule.min_matrix:
-            records.append(IngestRecord(raw.name, "filtered",
-                                        f"matrix {vol.shape} too small"))
-            continue
-        payload: Optional[bytes] = None
-        digest = ""
-        scan_qa = scan_stream = None
-        if device_qa and streaming:
-            qa, st, digest, payload, rep = _fast_qa_streamed(vol, rule)
-            checksum = f"{st.checksum:016x}"
-            scan_qa, scan_stream = dataclasses.asdict(st), rep.to_dict()
-            if stream_rep is None:
-                stream_rep = rep
+    with trace.span("ingest", dataset=dataset):
+        for raw in sorted(raw_dir.glob("*.npz")):
+            with trace.span("ingest.convert", bytes=raw.stat().st_size):
+                vol, meta, err = _convert(raw)
+            if err:
+                records.append(IngestRecord(raw.name, "corrupted", err))
+                continue
+            reason = _filter(vol, meta, rule)
+            if reason:
+                records.append(IngestRecord(raw.name, "filtered", reason))
+                continue
+            payload: Optional[bytes] = None
+            digest = ""
+            scan_qa = scan_stream = None
+            if device_qa and streaming:
+                qa, st, digest, payload, rep = _fast_qa_streamed(vol, rule)
+                checksum = f"{st.checksum:016x}"
+                scan_qa, scan_stream = dataclasses.asdict(st), rep.to_dict()
+                if stream_rep is None:
+                    stream_rep = rep
+                else:
+                    stream_rep.merge(rep)
             else:
-                stream_rep.merge(rep)
-        elif device_qa:
-            qa, checksum = _fast_qa_fused(vol, rule)
-        else:
-            qa, checksum = _fast_qa(vol, rule), ""
-        if qa:
-            records.append(IngestRecord(raw.name, "failed_qa", qa,
-                                        checksum=checksum, qa=scan_qa,
-                                        stream=scan_stream))
-            continue
-        # BIDS placement + JSON sidecar (dcm2niix behaviour)
-        sub, ses = meta["subject"], meta["session"]
-        modality = PROTOCOL_MODALITY.get(proto, "anat")
-        base = bids_root / dataset / f"sub-{sub}" / f"ses-{ses}" / modality
-        base.mkdir(parents=True, exist_ok=True)
-        stem = f"sub-{sub}_ses-{ses}_{proto}"
-        if payload is None:
-            buf = io.BytesIO()
-            np.save(buf, np.ascontiguousarray(vol, dtype=np.float32))
-            payload = buf.getvalue()
-            digest = hashlib.sha256(payload).hexdigest()
-        # commit the exact bytes the QA/digest pass saw, atomically
-        atomic_write_bytes(base / f"{stem}.npy", payload)
-        atomic_write_bytes(base / f"{stem}.json",
-                           json.dumps(meta, indent=1).encode(), fsync=False)
-        records.append(IngestRecord(raw.name, "ok",
-                                    dest=str(base / f"{stem}.npy"),
-                                    checksum=checksum, sha256=digest,
-                                    qa=scan_qa, stream=scan_stream))
-    manifest = DatasetManifest.scan(bids_root / dataset, name=dataset)
-    report = {
-        "dataset": dataset,
-        "counts": {s: sum(r.status == s for r in records)
-                   for s in ("ok", "corrupted", "filtered", "failed_qa")},
-        "records": [dataclasses.asdict(r) for r in records],
-    }
-    if stream_rep is not None:
-        report["stream"] = stream_rep.to_dict()
-    rp = bids_root / dataset / "ingestion_report.json"
-    rp.parent.mkdir(parents=True, exist_ok=True)
-    # tmp + fsync + rename (journal discipline): a crash mid-write must
-    # never leave a torn curation trail next to committed volumes
-    atomic_write_bytes(rp, json.dumps(report, indent=1).encode())
-    _fsync_dir(rp.parent)
+                with trace.span("ingest.verify", bytes=vol.size * 4):
+                    if device_qa:
+                        qa, checksum = _fast_qa_fused(vol, rule)
+                    else:
+                        qa, checksum = _fast_qa(vol, rule), ""
+            if qa:
+                records.append(IngestRecord(raw.name, "failed_qa", qa,
+                                            checksum=checksum, qa=scan_qa,
+                                            stream=scan_stream))
+                continue
+            # BIDS placement + JSON sidecar (dcm2niix behaviour)
+            sub, ses, proto = meta["subject"], meta["session"], \
+                meta["protocol"]
+            modality = PROTOCOL_MODALITY.get(proto, "anat")
+            base = bids_root / dataset / f"sub-{sub}" / f"ses-{ses}" \
+                / modality
+            base.mkdir(parents=True, exist_ok=True)
+            stem = f"sub-{sub}_ses-{ses}_{proto}"
+            if payload is None:
+                _, payload = _serialize(vol)
+                with trace.span("ingest.verify", bytes=len(payload)):
+                    digest = hashlib.sha256(payload).hexdigest()
+            # commit the exact bytes the QA/digest pass saw, atomically
+            sidecar = json.dumps(meta, indent=1).encode()
+            with trace.span("ingest.write", bytes=len(payload),
+                            suffix=".npy"):
+                atomic_write_bytes(base / f"{stem}.npy", payload)
+            with trace.span("ingest.write", bytes=len(sidecar),
+                            suffix=".json"):
+                atomic_write_bytes(base / f"{stem}.json", sidecar,
+                                   fsync=False)
+            records.append(IngestRecord(raw.name, "ok",
+                                        dest=str(base / f"{stem}.npy"),
+                                        checksum=checksum, sha256=digest,
+                                        qa=scan_qa, stream=scan_stream))
+        with trace.span("ingest.manifest"):
+            manifest = DatasetManifest.scan(bids_root / dataset, name=dataset)
+        with trace.span("ingest.report"):
+            report = {
+                "dataset": dataset,
+                "counts": {s: sum(r.status == s for r in records)
+                           for s in ("ok", "corrupted", "filtered",
+                                     "failed_qa")},
+                "records": [dataclasses.asdict(r) for r in records],
+            }
+            if stream_rep is not None:
+                report["stream"] = stream_rep.to_dict()
+            rp = bids_root / dataset / "ingestion_report.json"
+            rp.parent.mkdir(parents=True, exist_ok=True)
+            # tmp + fsync + rename (journal discipline): a crash mid-write
+            # must never leave a torn curation trail next to committed
+            # volumes
+            atomic_write_bytes(rp, json.dumps(report, indent=1).encode())
+            _fsync_dir(rp.parent)
     return manifest, records
+
+
+def _filter(vol: np.ndarray, meta: dict, rule: IngestRule) -> str:
+    """Why the scan is filtered out by protocol, resolution or matrix, or
+    ``""`` if it passes."""
+    proto = meta["protocol"]
+    if proto not in rule.allowed_protocols:
+        return f"protocol {proto} not in allow-list"
+    res = float(meta["resolution_mm"])
+    if not (rule.min_resolution_mm <= res <= rule.max_resolution_mm):
+        return f"resolution {res}mm out of bounds"
+    if min(vol.shape[:3]) < rule.min_matrix:
+        return f"matrix {vol.shape} too small"
+    return ""
 
 
 def _fsync_dir(path: Path):

@@ -21,6 +21,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from . import trace
 from .integrity import sha256_file
 
 # image payloads only — .json sidecars are metadata, not images
@@ -65,12 +66,15 @@ class DatasetManifest:
                 continue
             rel = p.relative_to(root)
             modality = rel.parts[2] if len(rel.parts) >= 4 else "anat"
+            size = p.stat().st_size
+            digest = ""
+            if checksum:
+                with trace.span("manifest.hash", bytes=size):
+                    digest = sha256_file(p)
             m.images.append(ImageRecord(
                 path=str(rel), subject=nm["sub"], session=nm["ses"],
-                modality=modality, suffix=nm["suffix"],
-                size_bytes=p.stat().st_size,
-                sha256=sha256_file(p) if checksum else "",
-                is_symlink=p.is_symlink()))
+                modality=modality, suffix=nm["suffix"], size_bytes=size,
+                sha256=digest, is_symlink=p.is_symlink()))
         m.scanned_at = time.time()
         return m
 

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
+
 
 @dataclasses.dataclass(frozen=True)
 class PipelineSpec:
@@ -55,7 +57,15 @@ class Pipeline:
         return self.spec.digest()
 
     def run(self, inputs: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-        return {k: np.asarray(v) for k, v in self.fn(inputs).items()}
+        """``fn``, then its outputs copied to the host (``stage.to_host``
+        also waits for the device where ``fn`` returns before its outputs
+        are ready)."""
+        with trace.span("stage.call", stage=self.name):
+            out = self.fn(inputs)
+        with trace.span("stage.to_host", stage=self.name,
+                        bytes=sum(getattr(v, "nbytes", 0)
+                                  for v in out.values())):
+            return {k: np.asarray(v) for k, v in out.items()}
 
 
 # ---------------------------------------------------------------------------

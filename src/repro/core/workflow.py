@@ -41,6 +41,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from . import stream as stream_mod
+from . import trace
 from .integrity import sha256_load_array, sha256_save_array
 from .manifest import DatasetManifest
 from .pipelines import Pipeline
@@ -242,6 +243,12 @@ LoadedInputs = Tuple[Dict[str, np.ndarray], Dict[str, str], bool, int, int,
                      Optional[Dict]]
 
 
+def unit_tag(unit: WorkUnit) -> str:
+    """``<pipeline>:<subject>:<session>``, the ``unit`` stat of every span
+    of a unit, so spans on the prefetch thread join their unit."""
+    return f"{unit.pipeline}:{unit.subject}:{unit.session}"
+
+
 def load_unit_inputs(unit: WorkUnit, data_root: Path,
                      cache=None) -> LoadedInputs:
     """Verify-and-load a unit's inputs with one read per file: each array is
@@ -273,31 +280,35 @@ def load_unit_inputs(unit: WorkUnit, data_root: Path,
     peer_bytes = 0
     stream_rep: Optional[stream_mod.StreamReport] = None
     streaming = cache is None and stream_mod.stream_enabled()
-    for suffix, rel in unit.inputs.items():
-        rep = None
-        if cache is not None:
-            arr, digest, origin, nbytes, info = cache.fetch_array(
-                data_root / rel, digest_hint=digests.get(suffix),
-                size_hint=sizes.get(suffix))
-            if info is not None:
-                rep = stream_mod.StreamReport.from_dict(info)
-            if origin == "cache":
-                hits += 1
-                hit_bytes += nbytes
-            elif origin == "peer":
-                peer_bytes += nbytes
-        elif streaming:
-            arr, digest, _qa, rep = stream_mod.stream_load_npy(
-                data_root / rel)
-        else:
-            arr, digest = sha256_load_array(data_root / rel)
-        if rep is not None:
-            if stream_rep is None:
-                stream_rep = rep
+    stats = {"unit": unit_tag(unit)}
+    if sizes:
+        stats["bytes"] = sum(sizes.values())
+    with trace.span("unit.load", **stats):
+        for suffix, rel in unit.inputs.items():
+            rep = None
+            if cache is not None:
+                arr, digest, origin, nbytes, info = cache.fetch_array(
+                    data_root / rel, digest_hint=digests.get(suffix),
+                    size_hint=sizes.get(suffix))
+                if info is not None:
+                    rep = stream_mod.StreamReport.from_dict(info)
+                if origin == "cache":
+                    hits += 1
+                    hit_bytes += nbytes
+                elif origin == "peer":
+                    peer_bytes += nbytes
+            elif streaming:
+                arr, digest, _qa, rep = stream_mod.stream_load_npy(
+                    data_root / rel)
             else:
-                stream_rep.merge(rep)
-        in_sums[rel] = digest
-        inputs[suffix] = arr
+                arr, digest = sha256_load_array(data_root / rel)
+            if rep is not None:
+                if stream_rep is None:
+                    stream_rep = rep
+                else:
+                    stream_rep.merge(rep)
+            in_sums[rel] = digest
+            inputs[suffix] = arr
     return (inputs, in_sums,
             bool(unit.inputs) and hits == len(unit.inputs), hit_bytes,
             peer_bytes,
@@ -357,51 +368,59 @@ def run_unit(unit: WorkUnit, pipeline: Pipeline, data_root: Path,
     t0 = time.time()
     data_root = Path(data_root)
     out_dir = Path(unit.out_dir)
-    if is_complete(out_dir, unit.pipeline_digest):
-        return UnitResult(unit, "skipped", 0.0, attempt)
-    try:
-        if fault_hook is not None:
-            fault_hook(unit, attempt)       # test hook: injected node failures
-        if preloaded is not None:
-            inputs, in_sums, cache_hit, hit_bytes, peer_bytes, stream = \
-                preloaded
-        else:
-            inputs, in_sums, cache_hit, hit_bytes, peer_bytes, stream = \
-                load_unit_inputs(unit, data_root, cache=cache)
-        outputs = pipeline.run(inputs)
-        out_sums = {}
-        out_dir.mkdir(parents=True, exist_ok=True)
-        for name, arr in outputs.items():
-            op = out_dir / f"sub-{unit.subject}_ses-{unit.session}_{name}.npy"
-            out_sums[op.name] = sha256_save_array(op, arr)
-        holder = _commit_lock(out_dir)   # keep referenced while lock is held
-        with holder.lock:
-            if is_complete(out_dir, unit.pipeline_digest):
-                return UnitResult(unit, "skipped", time.time() - t0, attempt)
-            make_provenance(unit.pipeline, unit.pipeline_digest, in_sums,
-                            out_sums, t0, attempt=attempt, node_id=node_id,
-                            lease_epoch=lease_epoch, cache_hit=cache_hit,
-                            locality_score=locality_score,
-                            bytes_from_cache=hit_bytes,
-                            peer_fetch=peer_bytes > 0,
-                            bytes_from_peer=peer_bytes,
-                            stream=stream).save(out_dir)
-        _write_outputs_through(cache, out_dir, out_sums)
-        return UnitResult(unit, "ok", time.time() - t0, attempt,
-                          bytes_from_cache=hit_bytes,
-                          bytes_from_peer=peer_bytes,
-                          locality_score=locality_score)
-    except Exception as e:  # noqa: BLE001 — recorded, retried by the runner
-        holder = _commit_lock(out_dir)
-        with holder.lock:
-            if not is_complete(out_dir, unit.pipeline_digest):
-                out_dir.mkdir(parents=True, exist_ok=True)
-                make_provenance(unit.pipeline, unit.pipeline_digest, {}, {}, t0,
-                                status="failed", error=f"{type(e).__name__}: {e}",
-                                attempt=attempt, node_id=node_id,
-                                lease_epoch=lease_epoch).save(out_dir)
-        return UnitResult(unit, "failed", time.time() - t0, attempt,
-                          error=traceback.format_exc(limit=3))
+    tag = unit_tag(unit)
+    with trace.span("unit", unit=tag, attempt=attempt):
+        if is_complete(out_dir, unit.pipeline_digest):
+            return UnitResult(unit, "skipped", 0.0, attempt)
+        try:
+            if fault_hook is not None:
+                fault_hook(unit, attempt)   # test hook: injected node failures
+            if preloaded is not None:
+                inputs, in_sums, cache_hit, hit_bytes, peer_bytes, stream = \
+                    preloaded
+            else:
+                inputs, in_sums, cache_hit, hit_bytes, peer_bytes, stream = \
+                    load_unit_inputs(unit, data_root, cache=cache)
+            outputs = pipeline.run(inputs)
+            out_sums = {}
+            out_dir.mkdir(parents=True, exist_ok=True)
+            for name, arr in outputs.items():
+                op = out_dir / \
+                    f"sub-{unit.subject}_ses-{unit.session}_{name}.npy"
+                with trace.span("unit.save", unit=tag, bytes=arr.nbytes):
+                    out_sums[op.name] = sha256_save_array(op, arr)
+            with trace.span("unit.commit", unit=tag):
+                holder = _commit_lock(out_dir)  # referenced while held
+                with holder.lock:
+                    if is_complete(out_dir, unit.pipeline_digest):
+                        return UnitResult(unit, "skipped", time.time() - t0,
+                                          attempt)
+                    make_provenance(unit.pipeline, unit.pipeline_digest,
+                                    in_sums, out_sums, t0, attempt=attempt,
+                                    node_id=node_id, lease_epoch=lease_epoch,
+                                    cache_hit=cache_hit,
+                                    locality_score=locality_score,
+                                    bytes_from_cache=hit_bytes,
+                                    peer_fetch=peer_bytes > 0,
+                                    bytes_from_peer=peer_bytes,
+                                    stream=stream).save(out_dir)
+                _write_outputs_through(cache, out_dir, out_sums)
+            return UnitResult(unit, "ok", time.time() - t0, attempt,
+                              bytes_from_cache=hit_bytes,
+                              bytes_from_peer=peer_bytes,
+                              locality_score=locality_score)
+        except Exception as e:  # noqa: BLE001 — recorded, retried
+            holder = _commit_lock(out_dir)
+            with holder.lock:
+                if not is_complete(out_dir, unit.pipeline_digest):
+                    out_dir.mkdir(parents=True, exist_ok=True)
+                    make_provenance(unit.pipeline, unit.pipeline_digest, {},
+                                    {}, t0, status="failed",
+                                    error=f"{type(e).__name__}: {e}",
+                                    attempt=attempt, node_id=node_id,
+                                    lease_epoch=lease_epoch).save(out_dir)
+            return UnitResult(unit, "failed", time.time() - t0, attempt,
+                              error=traceback.format_exc(limit=3))
 
 
 def run_unit_with_retries(
