@@ -15,6 +15,7 @@ the contribution is the orchestration around it):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 from pathlib import Path
@@ -138,8 +139,11 @@ def _trilinear(vol, coords):
     return out
 
 
-def _register_fn(inputs, steps=60, lr=5e-3):
-    moving = jnp.asarray(inputs["T1w"], jnp.float32)
+@functools.partial(jax.jit, static_argnames=("steps",))
+def _register(moving, lr, *, steps):
+    """The whole registration as one program per volume shape. The volume
+    is an argument, never a closed-over constant: a closure would embed it
+    in the program as a literal, and every new volume would compile anew."""
     moving = (moving - moving.mean()) / (moving.std() + 1e-6)
     # synthetic atlas: centered sphere intensity prior
     shape = moving.shape
@@ -153,14 +157,18 @@ def _register_fn(inputs, steps=60, lr=5e-3):
         return jnp.mean((warped - atlas) ** 2)
 
     theta = jnp.concatenate([jnp.eye(3), jnp.zeros((3, 1))], 1)
-    g = jax.jit(jax.value_and_grad(loss))
 
     def body(theta, _):
-        val, grad = g(theta)
+        val, grad = jax.value_and_grad(loss)(theta)
         return theta - lr * grad, val
     theta, losses = jax.lax.scan(body, theta, jnp.arange(steps))
     warped = _trilinear(moving, _affine_grid(shape, theta))
     return {"T1w_reg": warped, "affine": theta, "reg_loss": losses}
+
+
+def _register_fn(inputs, steps=60, lr=5e-3):
+    return _register(jnp.asarray(inputs["T1w"], jnp.float32), lr,
+                     steps=steps)
 
 
 # ---------------------------------------------------------------------------
@@ -208,8 +216,10 @@ def builtin_pipelines() -> Dict[str, Pipeline]:
         "bias_correct": Pipeline(
             PipelineSpec("bias_correct", "1.1", ("T1w",), {"order": 2}),
             _bias_correct_fn),
+        # 1.2: affine_register runs as one jitted program, which moves its
+        # outputs at rounding level, so 1.1's derivatives are planned again
         "affine_register": Pipeline(
-            PipelineSpec("affine_register", "1.1", ("T1w",),
+            PipelineSpec("affine_register", "1.2", ("T1w",),
                          {"steps": 60, "lr": 5e-3}),
             _register_fn),
         "segment_unest": Pipeline(
