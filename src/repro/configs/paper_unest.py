@@ -1,7 +1,10 @@
-"""paper-unest — the paper's own workload family: a UNesT-like hierarchical
-transformer used by the brain-segmentation pipeline (Yu et al. 2023, cited by the
-paper as one of its 16 processing pipelines). Modeled as a compact dense
-transformer backbone used by ``core/pipelines.py:SegmentationPipeline``."""
+"""paper-unest — the language-model-shaped backbone of ``segment_unest``
+(``core/pipelines.py:_segment_fn``): a dense 12x512 transformer stack with
+rotary positions, run there as its ``.reduced()`` preset over 3D patches.
+It is not UNesT's shape: the published UNesT model (Yu et al. 2023,
+arXiv:2209.14378; hierarchical block attention and a conv decoder) runs as
+the ``unest_wholebrain`` stage (``core/unest.py``), which uses nothing
+here."""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(

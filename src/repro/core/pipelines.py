@@ -9,8 +9,10 @@ the contribution is the orchestration around it):
 
   * bias_correct — N4-style low-order polynomial bias-field estimation
   * affine_register — gradient-descent affine registration to an atlas
-  * segment_unest — UNesT-like patch-transformer tissue segmentation
-    (backbone = configs/paper_unest.py)
+  * segment_unest — a language-model-shaped transformer over 3D patches
+    (backbone = configs/paper_unest.py), 4 tissue classes
+  * unest_wholebrain — the published UNesT whole-brain model (133 labels,
+    sliding 96³ windows; core/unest.py)
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import trace
+from . import trace, unest
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,6 +207,34 @@ def _segment_fn(inputs, n_classes=4, patch=4, seed=0):
 
 
 # ---------------------------------------------------------------------------
+# UNesT whole-brain segmentation (core/unest.py)
+# ---------------------------------------------------------------------------
+# Every published and assumed setting, JSON-equal to the benchmark
+# configuration's (lists, not tuples): the decoder plan, overlap, batch and
+# weight draws are assumed (core/unest.py), and so are constant blending and
+# z-score normalisation, which the stage always does.
+UNEST_WHOLEBRAIN = {
+    "classes": 133, "patch": 4, "embed_dims": [128, 256, 512],
+    "depths": [2, 2, 8], "num_heads": [4, 8, 16], "mlp_ratio": 4,
+    "feature_size": 32, "roi": 96, "overlap": 0.7, "sw_batch": 4,
+    "ln_eps": 1e-6, "in_eps": 1e-5, "leaky_slope": 0.01, "weight_seed": 0,
+}
+
+
+def _unest_fn(inputs, classes=133, patch=4, embed_dims=(128, 256, 512),
+              depths=(2, 2, 8), num_heads=(4, 8, 16), mlp_ratio=4,
+              feature_size=32, roi=96, overlap=0.7, sw_batch=4,
+              ln_eps=1e-6, in_eps=1e-5, leaky_slope=0.01, weight_seed=0):
+    cfg = unest.UNesTConfig(
+        classes=classes, patch=patch, embed_dims=tuple(embed_dims),
+        depths=tuple(depths), num_heads=tuple(num_heads),
+        mlp_ratio=mlp_ratio, feature_size=feature_size, roi=roi,
+        overlap=overlap, sw_batch=sw_batch, ln_eps=ln_eps, in_eps=in_eps,
+        leaky_slope=leaky_slope)
+    return unest.segment(inputs["T1w"], cfg, weight_seed)
+
+
+# ---------------------------------------------------------------------------
 # registry
 # ---------------------------------------------------------------------------
 
@@ -226,6 +256,10 @@ def builtin_pipelines() -> Dict[str, Pipeline]:
             PipelineSpec("segment_unest", "1.0", ("T1w",),
                          {"n_classes": 4, "patch": 4}),
             _segment_fn),
+        "unest_wholebrain": Pipeline(
+            PipelineSpec("unest_wholebrain", "1.0", ("T1w",),
+                         dict(UNEST_WHOLEBRAIN)),
+            _unest_fn),
         "dwi_prequal": Pipeline(
             PipelineSpec("dwi_prequal", "1.0", ("T1w", "dwi"),
                          {"denoise": "pca"}),

@@ -5,8 +5,9 @@ profiler trace (``jax.profiler.trace(dir)``) it is a host event
 ``repro.<name>`` with each keyword as an event stat, on the same clock as
 the device's ops; with no profiler running it costs about a microsecond.
 Every span also counts into process-wide counters: ``<name>.n`` and, where
-``bytes=`` is given, ``<name>.bytes``. ``counters()`` returns them with no
-profiler needed; ``reset()`` clears them.
+``bytes=`` is given, ``<name>.bytes``; ``count(name, value)`` adds to a
+counter of its own. ``counters()`` returns them with no profiler needed;
+``reset()`` clears them.
 
 JAX's compile phases become spans too. Importing this module registers
 listeners on ``jax.monitoring`` that open ``repro.compile`` (stats ``event``:
@@ -61,6 +62,11 @@ def span(name: str, **stats):
         _add(name + ".bytes", int(stats["bytes"]))
     with TraceAnnotation(PREFIX + name, **stats):
         yield
+
+
+def count(name: str, value: float = 1) -> None:
+    """Add ``value`` to the counter ``name``, with no span."""
+    _add(name, value)
 
 
 def counters() -> Dict[str, float]:
