@@ -81,15 +81,16 @@ def test_digest_change_triggers_reprocessing(dataset, tmp_path):
 @pytest.mark.parametrize("name,old_version", [
     pytest.param("bias_correct", "1.0", id="bias_correct"),
     pytest.param("affine_register", "1.0", id="affine_register"),
-    pytest.param("affine_register", "1.1", id="affine_register-1.1")])
+    pytest.param("affine_register", "1.1", id="affine_register-1.1"),
+    pytest.param("affine_register", "1.2", id="affine_register-1.2")])
 def test_changed_stage_replans_derivatives_of_its_old_version(
         name, old_version, dataset, tmp_path):
     """bias_correct's fit (lstsq rcond) and both stages' contractions
     (HIGHEST precision) changed what they compute, so their version moved
     past 1.0; affine_register's one jitted program moved its outputs at
-    rounding level, so it moved past 1.1. A derivative committed under an
-    older version must be planned again, not skipped as already
-    processed."""
+    rounding level, so it moved past 1.1, and its corner-table sampler did
+    again, so it moved past 1.2. A derivative committed under an older
+    version must be planned again, not skipped as already processed."""
     import dataclasses
     pipe = builtin_pipelines()[name]
     old = type(pipe)(dataclasses.replace(pipe.spec, version=old_version),

@@ -1,12 +1,29 @@
-"""affine_register compiles once per volume shape, and computes what the
-closure formulation it replaced computed."""
+"""affine_register compiles once per volume shape, computes what the
+closure formulation it replaced computed, and samples with one gather a
+voxel from a corner table, as the eight-gather sampler it replaced did."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import builtin_pipelines, trace
-from repro.core.pipelines import _affine_grid, _register, _trilinear
+from repro.core.pipelines import (_affine_grid, _corner_table, _register,
+                                  _trilinear)
+
+
+def _eight_gather_trilinear(vol, coords):
+    """The sampler the corner table replaced, frozen as the oracle: one
+    gather of single voxels per corner, eight a sample."""
+    x, y, z = coords[..., 0], coords[..., 1], coords[..., 2]
+    x0, y0, z0 = (jnp.clip(jnp.floor(c).astype(jnp.int32), 0, s - 2)
+                  for c, s in zip((x, y, z), vol.shape))
+    dx, dy, dz = x - x0, y - y0, z - z0
+    out = 0.0
+    for ix, wx in ((x0, 1 - dx), (x0 + 1, dx)):
+        for iy, wy in ((y0, 1 - dy), (y0 + 1, dy)):
+            for iz, wz in ((z0, 1 - dz), (z0 + 1, dz)):
+                out = out + vol[ix, iy, iz] * wx * wy * wz
+    return out
 
 
 @pytest.fixture()
@@ -68,7 +85,7 @@ def _closure_register(inputs, steps=60, lr=5e-3):
     atlas = (atlas - atlas.mean()) / (atlas.std() + 1e-6)
 
     def loss(theta):
-        warped = _trilinear(moving, _affine_grid(shape, theta))
+        warped = _eight_gather_trilinear(moving, _affine_grid(shape, theta))
         return jnp.mean((warped - atlas) ** 2)
 
     theta = jnp.concatenate([jnp.eye(3), jnp.zeros((3, 1))], 1)
@@ -78,7 +95,7 @@ def _closure_register(inputs, steps=60, lr=5e-3):
         val, grad = g(theta)
         return theta - lr * grad, val
     theta, losses = jax.lax.scan(body, theta, jnp.arange(steps))
-    warped = _trilinear(moving, _affine_grid(shape, theta))
+    warped = _eight_gather_trilinear(moving, _affine_grid(shape, theta))
     return {"T1w_reg": warped, "affine": theta, "reg_loss": losses}
 
 
@@ -107,3 +124,66 @@ def test_register_agrees_with_closure_formulation(seed):
     np.testing.assert_allclose(got["reg_loss"], want["reg_loss"], rtol=1e-5)
     span = float(np.ptp(want["T1w_reg"]))
     assert np.abs(got["T1w_reg"] - want["T1w_reg"]).max() <= 1e-3 * span
+
+
+# Near the identity every sample lies inside the volume; the second affine
+# scales by 1.3 and shifts by 0.4 of the half-width, so a large share of
+# samples lies outside and the clip to the edge cells acts.
+_AFFINES = {
+    "near_identity": np.eye(3, 4) + np.array([[0.02, -0.03, 0.01, 0.05],
+                                               [0.01, 0.03, -0.02, -0.04],
+                                               [-0.02, 0.01, 0.02, 0.03]]),
+    "outside": np.concatenate([1.3 * np.eye(3), 0.4 * np.ones((3, 1))], 1),
+}
+
+
+@pytest.mark.parametrize("affine", sorted(_AFFINES))
+@pytest.mark.parametrize("shape", [(24, 20, 16), (31, 27, 19), (16, 33, 9)])
+def test_corner_table_sampler_matches_eight_gathers(shape, affine):
+    """The table holds the same float32 corner values, and the sampler
+    sums the same eight products with the same weights in the same order;
+    only XLA's fusion can reorder a rounding, which moves a sample by a
+    few float32 epsilons of the volume's range (0 here on the CPU, 4.8e-7
+    with other data), so 1e-6 of the range holds it on every backend,
+    while two swapped corners move a sample by 5e-2 of the range or more.
+    The gradient with respect to theta flows only through the weights,
+    which are computed as before (it reads 0 apart here), so it is held to
+    1e-6 of its largest entry on the same reasoning."""
+    vol = jnp.asarray(_smooth_volume(shape, 5))
+    target = jnp.asarray(_smooth_volume(shape, 6)[::-1])
+    theta = jnp.asarray(_AFFINES[affine], jnp.float32)
+    coords = _affine_grid(shape, theta)
+    if affine == "outside":
+        inside = np.all((np.asarray(coords) >= 0)
+                        & (np.asarray(coords) <= np.array(shape) - 1), -1)
+        assert inside.mean() < 0.5                # the clip acts
+    table = _corner_table(vol)
+
+    def new(th):
+        return _trilinear(table, shape, _affine_grid(shape, th))
+
+    def old(th):
+        return _eight_gather_trilinear(vol, _affine_grid(shape, th))
+
+    got, want = jax.jit(new)(theta), jax.jit(old)(theta)
+    span = float(jnp.max(vol) - jnp.min(vol))
+    assert float(jnp.abs(got - want).max()) <= 1e-6 * span
+
+    def grad(sample):
+        return jax.jit(jax.grad(
+            lambda th: jnp.mean((sample(th) - target) ** 2) / span ** 2))(theta)
+    g_new, g_old = grad(new), grad(old)
+    assert float(jnp.abs(g_old).max()) > 0
+    assert float(jnp.abs(g_new - g_old).max()) \
+        <= 1e-6 * float(jnp.abs(g_old).max())
+
+
+def test_register_gathers_once_per_sample():
+    """One gather in the scan's body (the loss's sampler; its gradient
+    reuses the gathered rows) and one in the final warp, and no scatter:
+    the corner table is not differentiated. The eight-gather sampler held
+    16 gathers here, so a return to it fails."""
+    text = _register.lower(jnp.zeros((24, 20, 16), jnp.float32), 5e-3,
+                           steps=2).as_text()
+    assert text.count('"stablehlo.gather"(') == 2
+    assert text.count('"stablehlo.scatter"(') == 0
