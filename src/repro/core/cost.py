@@ -1,8 +1,9 @@
 """Cost model (paper §2.4, Table 1) — HPC vs cloud vs local economics,
 extended to TPU-pod training economics for this framework's scale.
 
-Paper constants are encoded verbatim so ``benchmarks/table1_cost.py``
-reproduces the published table; ``job_cost`` generalizes to any workload.
+Paper constants are encoded verbatim so ``paper_table1`` reproduces the
+published table (``tests/test_storage_ckpt.py``); ``job_cost`` generalizes
+to any workload.
 """
 from __future__ import annotations
 

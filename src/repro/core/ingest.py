@@ -13,11 +13,11 @@ Here the scanner hand-off is a directory of raw dumps: ``<id>.raw.npz``
   2. filter: protocol allow-list, resolution / matrix-dimension bounds.
   3. fast QA: intensity sanity (finite, non-constant, SNR proxy); with
      ``device_qa`` the finite/constant/mean passes and the transfer checksum
-     fuse into ONE Pallas kernel launch per volume (kernels/checksum). With
-     streaming on (the default, ``repro.core.stream``) the serialized volume
-     is chunked through the fold + an incremental sha256, so the integrity
-     digest and the QA verdict land together — no second host-side pass —
-     and the recorded checksum is bit-identical to the one-shot kernel's.
+     come from the fused Pallas QA+checksum fold (kernels/checksum), run
+     over the serialized volume chunk by chunk beside an incremental sha256
+     (``repro.core.stream``), so the integrity digest and the QA verdict
+     land together — no second host-side pass — and the recorded checksum
+     is bit-identical to the one-shot kernel's.
   4. organize: BIDS tree ``sub-*/ses-*/<modality>/...`` + manifest scan.
      Accepted volumes and the ingestion report commit via atomic
      tmp+fsync+rename (a crash mid-ingest never leaves a torn file).
@@ -118,10 +118,10 @@ def _fast_qa(vol: np.ndarray, rule: IngestRule) -> str:
 
 
 def _qa_verdict(st, vol: np.ndarray, rule: IngestRule) -> str:
-    """The fused-kernel QA decision, shared by the one-shot and streamed
-    paths: ``st`` is a ``QAStats`` (from ``qa_stats`` or the chunk
-    accumulator — bit-identical either way), ``vol`` the float32 volume
-    (only its corner octant is touched, for the SNR background std)."""
+    """The fused-kernel QA decision, equivalent to :func:`_fast_qa`: ``st``
+    is a ``QAStats`` from the chunk accumulator (bit-identical to the
+    one-shot ``qa_stats``), ``vol`` the float32 volume (only its corner
+    octant is touched, for the SNR background std)."""
     if st.finite_count < vol.size:
         return "non-finite voxels"
     if st.vmin == st.vmax:
@@ -132,23 +132,6 @@ def _qa_verdict(st, vol: np.ndarray, rule: IngestRule) -> str:
     if snr < rule.min_snr:
         return f"low SNR proxy ({snr:.2f})"
     return ""
-
-
-def _fast_qa_fused(vol: np.ndarray, rule: IngestRule) -> Tuple[str, str]:
-    """QA + transfer checksum in ONE device pass (kernels/checksum).
-
-    Returns ``(reason, checksum_hex)``. Semantically equivalent to
-    :func:`_fast_qa` — the full-volume finite / constant / mean passes come
-    from the fused kernel's (min, max, sum, finite_count); only the SNR
-    background std still touches the tiny corner octant (1/64 of voxels) on
-    the host. The checksum rides along for free and is recorded so the BIDS
-    transfer can be verified without another read; it is computed over the
-    float32 view — the exact dtype :func:`ingest_directory` stores — so a
-    later device-side pass over the saved array reproduces it."""
-    from ..kernels.checksum import qa_stats
-    vol = np.ascontiguousarray(vol, dtype=np.float32)
-    st = qa_stats(vol)
-    return _qa_verdict(st, vol, rule), f"{st.checksum:016x}"
 
 
 def _serialize(vol: np.ndarray) -> Tuple[np.ndarray, bytes]:
@@ -164,13 +147,13 @@ def _serialize(vol: np.ndarray) -> Tuple[np.ndarray, bytes]:
 def _fast_qa_streamed(vol: np.ndarray, rule: IngestRule
                       ) -> Tuple[str, QAStats, str, bytes,
                                  stream_mod.StreamReport]:
-    """The streaming twin of :func:`_fast_qa_fused`: serialize the float32
-    volume once, then chunk those bytes through the incremental sha256 and
-    the chunk-accumulating fused kernel fold (``repro.core.stream``), so the
-    content digest of the exact bytes about to be committed and the QA
-    verdict land together — no load-then-verify second pass, and on an
-    accelerator each chunk's fold dispatch overlaps the next chunk's
-    hashing. Returns ``(reason, qa_stats, sha256_hex, npy_bytes,
+    """Device QA + transfer checksum: serialize the float32 volume once
+    (the dtype :func:`ingest_directory` stores), then chunk those bytes
+    through the incremental sha256 and the chunk-accumulating fused kernel
+    fold (``repro.core.stream``), so the content digest of the exact bytes
+    about to be committed and the QA verdict land together — no second
+    pass, and on an accelerator each chunk's fold dispatch overlaps the
+    next chunk's hashing. Returns ``(reason, qa_stats, sha256_hex, npy_bytes,
     report)``; the stats are bit-identical to the one-shot kernel's (same
     blocks, same fold order) and ``npy_bytes`` is what
     :func:`ingest_directory` commits, so digest == sha256 of the file. A
@@ -192,18 +175,17 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
     ``device_qa=True`` routes the fast-QA stage through the fused Pallas
     QA+checksum kernel — one device pass per volume instead of ~5 numpy
     passes — and records the transfer checksum on each accepted scan.
-    Defaults to the ``REPRO_DEVICE_QA`` env var (off). With streaming on
-    (the default; ``REPRO_STREAM_INGEST=0`` disables) the device-QA path
+    Defaults to the ``REPRO_DEVICE_QA`` env var (off). The device-QA path
     chunks the serialized volume through the fold + an incremental sha256
     (``repro.core.stream``), committing exactly the verified bytes and
-    recording their content digest on each record."""
+    recording their content digest on each record; the host path runs
+    :func:`_fast_qa`, then hashes the serialized bytes it commits."""
     # construct the default per call: a shared mutable default instance
     # would leak one caller's rule edits into every later call
     rule = IngestRule() if rule is None else rule
     if device_qa is None:
         device_qa = os.environ.get("REPRO_DEVICE_QA", "0").lower() \
             not in ("0", "", "false")
-    streaming = stream_mod.stream_enabled()
     raw_dir, bids_root = Path(raw_dir), Path(bids_root)
     records: List[IngestRecord] = []
     stream_rep: Optional[stream_mod.StreamReport] = None
@@ -221,7 +203,7 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
             payload: Optional[bytes] = None
             digest = ""
             scan_qa = scan_stream = None
-            if device_qa and streaming:
+            if device_qa:
                 qa, st, digest, payload, rep = _fast_qa_streamed(vol, rule)
                 checksum = f"{st.checksum:016x}"
                 scan_qa, scan_stream = dataclasses.asdict(st), rep.to_dict()
@@ -231,10 +213,7 @@ def ingest_directory(raw_dir: Path, bids_root: Path, dataset: str,
                     stream_rep.merge(rep)
             else:
                 with trace.span("ingest.verify", bytes=vol.size * 4):
-                    if device_qa:
-                        qa, checksum = _fast_qa_fused(vol, rule)
-                    else:
-                        qa, checksum = _fast_qa(vol, rule), ""
+                    qa, checksum = _fast_qa(vol, rule), ""
             if qa:
                 records.append(IngestRecord(raw.name, "failed_qa", qa,
                                             checksum=checksum, qa=scan_qa,

@@ -8,7 +8,7 @@ Three tiers with the paper's cost/bandwidth characteristics:
 
 Every put/get is checksummed (IntegrityError on mismatch). Transfers are
 accounted (bytes, simulated seconds from tier bandwidth) so the cost model
-and benchmarks can reproduce the paper's Table 1 without real networks.
+can reproduce the paper's Table 1 without real networks.
 """
 from __future__ import annotations
 

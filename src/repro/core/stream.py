@@ -35,9 +35,11 @@ Per-stage wall times land in a :class:`StreamReport` — ``overlap_s`` is the
 time the pipeline saved versus running the stages back-to-back — which the
 callers stamp into provenance (``stream``) and ``InputCache.stats()``.
 
-Runbook knobs (docs/operating.md): ``REPRO_STREAM_CHUNK_MB`` sizes the
-chunk (default 4 MiB), ``REPRO_STREAM_INGEST=0`` disables streaming
-everywhere and restores the load-then-verify sequence.
+This is the one verified transfer path: device-QA ingest, cache-less unit
+loads and ``InputCache`` storage misses all stream.
+
+Runbook knob (docs/operating.md): ``REPRO_STREAM_CHUNK_MB`` sizes the
+chunk (default 4 MiB).
 """
 from __future__ import annotations
 
@@ -55,16 +57,9 @@ import numpy as np
 
 from ..kernels.checksum import QAChecksumAccumulator, StreamSizeError
 
-STREAM_ENV = "REPRO_STREAM_INGEST"
 CHUNK_MB_ENV = "REPRO_STREAM_CHUNK_MB"
 DEFAULT_CHUNK_BYTES = 4 << 20
 MIN_CHUNK_BYTES = 64 << 10
-
-
-def stream_enabled() -> bool:
-    """Streaming is the default data plane; ``REPRO_STREAM_INGEST=0`` is
-    the kill switch back to load-then-verify."""
-    return os.environ.get(STREAM_ENV, "1").lower() not in ("0", "", "false")
 
 
 def stream_chunk_bytes() -> int:

@@ -8,7 +8,7 @@ Execution data plane (``LocalRunner``) is built for throughput:
 * **Multi-worker executor** — ``workers=N`` compute threads drain the unit
   list concurrently (XLA/BLAS release the GIL, so pipeline compute overlaps).
 * **Pipelined prefetch** — a loader stage verifies+hashes+loads the next
-  units' inputs (one read per byte, see ``integrity.sha256_load_array``)
+  units' inputs (one read per byte, see ``stream.stream_load_npy``)
   while compute runs the current ones; lookahead is bounded by
   ``workers + prefetch`` units so memory stays flat.
 * **Idempotent, concurrency-safe commits** — outputs are written via atomic
@@ -42,7 +42,7 @@ import numpy as np
 
 from . import stream as stream_mod
 from . import trace
-from .integrity import sha256_load_array, sha256_save_array
+from .integrity import sha256_save_array
 from .manifest import DatasetManifest
 from .pipelines import Pipeline
 from .provenance import make_provenance, is_complete
@@ -268,8 +268,8 @@ def load_unit_inputs(unit: WorkUnit, data_root: Path,
     the bytes that arrived over peer links (``bytes_from_peer``). The sixth
     is the unit's aggregated streaming-ingest report (digests computed
     chunk-by-chunk while the bytes moved, ``repro.core.stream``; ``None``
-    when every input was served resident or streaming is disabled) —
-    stamped into provenance as ``stream``."""
+    when every input was served resident) — stamped into provenance as
+    ``stream``."""
     data_root = Path(data_root)
     inputs: Dict[str, np.ndarray] = {}
     in_sums: Dict[str, str] = {}
@@ -279,7 +279,6 @@ def load_unit_inputs(unit: WorkUnit, data_root: Path,
     hit_bytes = 0
     peer_bytes = 0
     stream_rep: Optional[stream_mod.StreamReport] = None
-    streaming = cache is None and stream_mod.stream_enabled()
     stats = {"unit": unit_tag(unit)}
     if sizes:
         stats["bytes"] = sum(sizes.values())
@@ -297,11 +296,9 @@ def load_unit_inputs(unit: WorkUnit, data_root: Path,
                     hit_bytes += nbytes
                 elif origin == "peer":
                     peer_bytes += nbytes
-            elif streaming:
+            else:
                 arr, digest, _qa, rep = stream_mod.stream_load_npy(
                     data_root / rel)
-            else:
-                arr, digest = sha256_load_array(data_root / rel)
             if rep is not None:
                 if stream_rep is None:
                     stream_rep = rep
@@ -328,14 +325,12 @@ def safe_load_unit_inputs(unit: WorkUnit, data_root: Path,
 # Output write-through (multi-stage DAGs): the committing run inserts its
 # just-written outputs into the host's input cache, so a dependent unit
 # scheduled on the same host (producer placement) serves stage-N outputs as
-# stage-N+1 inputs off local disk. Env-disable for benchmarks that need a
-# warm-up whose caches hold inputs only.
-WRITE_THROUGH_ENV = "REPRO_CACHE_WRITE_THROUGH"
+# stage-N+1 inputs off local disk.
 
 
 def _write_outputs_through(cache, out_dir: Path, out_sums: Dict[str, str]):
     """Best-effort: a cache insert must never fail a committed unit."""
-    if cache is None or os.environ.get(WRITE_THROUGH_ENV, "1") == "0":
+    if cache is None:
         return
     for name, digest in out_sums.items():
         try:

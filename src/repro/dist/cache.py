@@ -405,28 +405,6 @@ class InputCache:
         cache after attempt 1) inherits the peer path with no new plumbing."""
         self.fabric = fabric
 
-    @staticmethod
-    def _read_storage(src: Path) -> bytes:
-        """The one seam every shared-storage read crosses. Benchmarks
-        monkeypatch this to model the paper's 0.60 Gb/s storage link without
-        faking the peer path; production never overrides it."""
-        return Path(src).read_bytes()
-
-    def _storage_chunks(self, src: Path, chunk_bytes: int):
-        """Chunked twin of :meth:`_read_storage` for the streaming data
-        plane (``repro.core.stream``): yields the file's bytes in
-        ``chunk_bytes`` pieces so hashing and QA can overlap the transfer.
-        When a benchmark (or test) has monkeypatched ``_read_storage`` to
-        model the storage link, that seam is honored — its whole-file
-        result is re-chunked, so the modeled link cost still lands on the
-        read stage — and benchmarks that model a *chunked* link override
-        this method directly."""
-        rs = self._read_storage
-        if rs is not _DEFAULT_READ_STORAGE:
-            yield from stream_mod.bytes_chunks(rs(src), chunk_bytes)
-        else:
-            yield from stream_mod.file_chunks(Path(src), chunk_bytes)
-
     def _insert_blob(self, digest: str, data: bytes, key: Optional[str]):
         """Commit ``data`` as blob ``digest``, map ``key`` to it (when
         given), then evict down to budget. The multi-MB blob write happens
@@ -467,11 +445,11 @@ class InputCache:
         size that moved over (or stayed off) each link. ``stream`` is the
         :class:`repro.core.stream.StreamReport` dict for a chunk-streamed
         storage miss (digest — and with ``device_qa`` the fused QA fold —
-        computed while the bytes moved; see ``REPRO_STREAM_INGEST``), else
-        ``None``. On a local miss, a manifest ``digest_hint`` plus an
-        attached fabric tries the warmest peer first; any peer failure
-        falls back to one storage read, after which the bytes are inserted
-        locally (then evicted down to ``max_bytes``). ``size_hint`` (the
+        computed while the bytes moved), else ``None``. On a local miss, a
+        manifest ``digest_hint`` plus an attached fabric tries the warmest
+        peer first; any peer failure falls back to one storage read, after
+        which the bytes are inserted locally (then evicted down to
+        ``max_bytes``). ``size_hint`` (the
         manifest's byte count) guards the peer path against a source file
         rewritten since the manifest scan: on size disagreement the fetch
         goes straight to storage so it observes the current bytes."""
@@ -548,36 +526,28 @@ class InputCache:
                     self._insert_blob(digest_hint, data,
                                       key if st_size == len(data) else None)
                 return arr, digest_hint, "peer", len(data), None
-        # storage: one pass over the shared link. With streaming on (the
-        # default) the bytes cross chunk-by-chunk and the sha256 — plus,
-        # when asked, the fused device QA fold — runs *while* they move; a
-        # prefetch thread keeps the link busy during each chunk's hashing.
-        # REPRO_STREAM_INGEST=0 restores the read-then-hash sequence.
-        stream_info: Optional[dict] = None
+        # storage: one pass over the shared link. The bytes cross
+        # chunk-by-chunk and the sha256 — plus, when asked, the fused device
+        # QA fold — runs *while* they move; a prefetch thread keeps the link
+        # busy during each chunk's hashing.
         t0 = time.perf_counter()
-        if stream_mod.stream_enabled():
-            cb = stream_mod.stream_chunk_bytes()
-            pf = stream_mod._Prefetcher(self._storage_chunks(src, cb))
-            data, digest, _qa, rep = stream_mod.stream_chunks(
-                pf, npy_qa=device_qa, chunk_bytes=cb, prefetch=pf)
-            stream_info = rep.to_dict()
-        else:
-            data = self._read_storage(src)
-            digest = hashlib.sha256(data).hexdigest()
-            rep = None
+        cb = stream_mod.stream_chunk_bytes()
+        pf = stream_mod._Prefetcher(stream_mod.file_chunks(src, cb))
+        data, digest, _qa, rep = stream_mod.stream_chunks(
+            pf, npy_qa=device_qa, chunk_bytes=cb, prefetch=pf)
+        stream_info = rep.to_dict()
         dt = time.perf_counter() - t0
         arr = np.load(io.BytesIO(data), allow_pickle=False)
         with self._lock:
             self.misses += 1
             self.bytes_from_storage += len(data)
             self.storage_seconds += dt
-            if rep is not None:
-                self.stream_fetches += 1
-                self.stream_bytes += rep.nbytes
-                self.stream_chunks += rep.chunks
-                self.stream_hash_seconds += rep.hash_s
-                self.stream_device_seconds += rep.device_s
-                self.stream_overlap_seconds += rep.overlap_s
+            self.stream_fetches += 1
+            self.stream_bytes += rep.nbytes
+            self.stream_chunks += rep.chunks
+            self.stream_hash_seconds += rep.hash_s
+            self.stream_device_seconds += rep.device_s
+            self.stream_overlap_seconds += rep.overlap_s
         if len(data) > self.max_bytes:
             # an input bigger than the whole budget can never be served
             # later; inserting it would wipe every warm blob on the host
@@ -696,12 +666,6 @@ class InputCache:
     def stats(self) -> Dict[str, object]:
         with self._lock:
             return self._stats_locked()
-
-
-# Captured at import so ``_storage_chunks`` can tell whether a benchmark or
-# test has monkeypatched the ``_read_storage`` seam (the modeled link then
-# keeps its cost, re-chunked).
-_DEFAULT_READ_STORAGE = InputCache._read_storage
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ the next rung — a cluster of :class:`Node` workers draining one
 :class:`~repro.dist.queue.WorkQueue` of work units:
 
 * **Per-node prefetch** — each node leases a small in-hand window of units
-  and verifies+loads their inputs (``sha256_load_array``, one read per byte)
+  and verifies+loads their inputs (``load_unit_inputs``, one read per byte)
   on a loader thread while the current unit computes. Only *leased* units are
   prefetched, so work-stealing never invalidates a node's prefetch.
 * **Work stealing** — a node that drains its deque steals the tail half of

@@ -20,7 +20,7 @@ import pytest
 from repro.core import (Provenance, builtin_pipelines, query_available_work,
                         synthesize_dataset)
 from repro.core.query import WorkUnit, dump_units, load_units
-from repro.core.workflow import WRITE_THROUGH_ENV, run_unit
+from repro.core.workflow import run_unit
 from repro.dist import ClusterRunner, WorkQueue
 from repro.dist.cache import InputCache
 from repro.dist.rpc import _decode, _encode
@@ -417,14 +417,3 @@ def test_committed_outputs_are_written_through_to_the_cache(dataset,
         blob = cache.read_blob(digest)
         assert blob is not None
         assert hashlib.sha256(blob).hexdigest() == digest
-
-
-def test_write_through_env_kill_switch(dataset, tmp_path, monkeypatch):
-    monkeypatch.setenv(WRITE_THROUGH_ENV, "0")
-    pipes = builtin_pipelines()
-    units, _ = query_available_work(dataset, pipes["bias_correct"])
-    cache = InputCache(tmp_path / "cache")
-    res = run_unit(units[0], pipes["bias_correct"], dataset.root, cache=cache)
-    assert res.status == "ok"
-    prov = Provenance.load(Path(units[0].out_dir))
-    assert all(cache.read_blob(d) is None for d in prov.outputs.values())

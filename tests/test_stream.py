@@ -4,14 +4,13 @@ in-flight digests, pipeline fallbacks, and the data-plane wiring
 import hashlib
 import io
 import os
-from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.core import stream as stream_mod
 from repro.core.stream import (StreamReport, bytes_chunks, stream_chunks,
-                               stream_enabled, stream_chunk_bytes,
+                               stream_chunk_bytes,
                                stream_file, stream_load_npy,
                                stream_verify_bytes, _Prefetcher)
 from repro.core.integrity import sha256_load_array
@@ -170,10 +169,8 @@ def test_stream_report_merge_and_overlap():
 
 
 def test_stream_knobs(monkeypatch):
-    monkeypatch.delenv(stream_mod.STREAM_ENV, raising=False)
-    assert stream_enabled()
-    monkeypatch.setenv(stream_mod.STREAM_ENV, "0")
-    assert not stream_enabled()
+    monkeypatch.delenv(stream_mod.CHUNK_MB_ENV, raising=False)
+    assert stream_chunk_bytes() == stream_mod.DEFAULT_CHUNK_BYTES
     monkeypatch.setenv(stream_mod.CHUNK_MB_ENV, "2")
     assert stream_chunk_bytes() == 2 << 20
     monkeypatch.setenv(stream_mod.CHUNK_MB_ENV, "0.001")   # floored
@@ -206,38 +203,18 @@ def test_fetch_array_streams_storage_misses(tmp_path):
     assert (origin2, info2) == ("cache", None) and d2 == digest
 
 
-def test_fetch_array_streaming_disabled_is_identical(tmp_path, monkeypatch):
-    vol = _volume((8, 8, 8), np.float32)
+def test_fetch_array_stream_matches_sha256_load_array(tmp_path, monkeypatch):
+    """A streamed storage miss returns the same digest and array as the
+    one-read reference loader, across several chunks and a short tail."""
+    vol = _volume((48, 48, 48), np.float32)
     src = tmp_path / "v.npy"
     np.save(src, vol)
-    monkeypatch.setenv(stream_mod.STREAM_ENV, "0")
-    cold = InputCache(tmp_path / "off")
-    arr, digest, origin, nbytes, info = cold.fetch_array(src)
-    assert info is None and cold.stats()["stream_fetches"] == 0
-    monkeypatch.delenv(stream_mod.STREAM_ENV)
-    warm = InputCache(tmp_path / "on")
-    arr2, digest2, *_ = warm.fetch_array(src)
-    assert digest2 == digest and np.array_equal(arr2, arr)
-
-
-def test_fetch_array_respects_read_storage_seam(tmp_path, monkeypatch):
-    """Benchmarks model the storage link by monkeypatching _read_storage;
-    the chunked reader must route through the override, not around it."""
-    vol = _volume((8, 8), np.float32)
-    src = tmp_path / "v.npy"
-    np.save(src, vol)
-    calls = []
-
-    def tracked(path):
-        calls.append(Path(path))
-        return Path(path).read_bytes()
-
-    monkeypatch.setattr(InputCache, "_read_storage", staticmethod(tracked))
-    cache = InputCache(tmp_path / "c")
-    _, digest, origin, _, info = cache.fetch_array(src)
-    assert origin == "storage" and calls == [src]
-    assert digest == hashlib.sha256(src.read_bytes()).hexdigest()
-    assert info is not None            # still chunk-hashed after the seam
+    monkeypatch.setenv(stream_mod.CHUNK_MB_ENV, "0.0625")
+    ref_arr, ref_digest = sha256_load_array(src)
+    arr, digest, origin, nbytes, info = InputCache(
+        tmp_path / "c").fetch_array(src)
+    assert origin == "storage" and info["chunks"] > 1
+    assert digest == ref_digest and np.array_equal(arr, ref_arr)
 
 
 def test_load_unit_inputs_aggregates_stream_reports(tmp_path):
